@@ -16,7 +16,6 @@ high-confidence tokens, split at the median accurate-run top-2 margin).
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineContext, FXP8, FXP16, PrecisionPolicy
@@ -44,7 +43,7 @@ from ._common import (
 def bench_load(model, cfg, params, bank, n_requests, *, slots, prompt_len,
                max_new, cycle_budget, fmt):
     ctx = EngineContext(mode=bank.mode, policy=PrecisionPolicy.accurate(fmt),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     max_len = prompt_len + max_new + 2
     workload = lambda: make_requests(cfg, n_requests, prompt_len=prompt_len,
                                      max_new=max_new)

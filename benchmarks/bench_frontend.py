@@ -32,7 +32,6 @@ from __future__ import annotations
 import sys
 import time
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineContext
@@ -61,7 +60,7 @@ IDENTITY_ARCHS = {
 def _build(arch, args, *, max_len, resilience=None):
     cfg, model, params = load_model(arch, full_size=args.full_size,
                                     d_model=args.d_model)
-    ctx = EngineContext(mode="exact", compute_dtype=jnp.float32)
+    ctx = EngineContext(mode="exact", compute_dtype=cfg.compute_dtype)
     srv = BatchedServer(model, ctx, params, slots=args.slots, max_len=max_len,
                         burst=args.burst, resilience=resilience)
     return cfg, srv

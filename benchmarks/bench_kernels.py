@@ -108,7 +108,7 @@ def main(argv=None):
 
     cfg, model, params = load_model(args.arch, full_size=args.full_size)
     base = EngineContext(mode="kernel", policy=PrecisionPolicy.accurate(FXP8),
-                         compute_dtype=jnp.float32)
+                         compute_dtype=cfg.compute_dtype)
 
     results = {}
     for fused in ("off", "on"):

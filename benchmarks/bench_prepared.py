@@ -32,7 +32,8 @@ from ._common import (
 
 def bench_mode(model, params, mode: str, *, slots: int, max_len: int, steps: int):
     policy = PrecisionPolicy.accurate(FXP8)
-    ctx = EngineContext(mode=mode, policy=policy, compute_dtype=jnp.float32)
+    ctx = EngineContext(mode=mode, policy=policy,
+                        compute_dtype=model.cfg.compute_dtype)
     prepared = prepare_params(params, policy, mode, specs=model.specs())
     rec = {}
     for label, p in (("per_call", params), ("prepared", prepared)):
@@ -74,7 +75,7 @@ def main(argv=None):
     # this record also carries SLO latency percentiles, not just step_ms
     mode = args.modes.split(",")[0]
     ctx = EngineContext(mode=mode, policy=PrecisionPolicy.accurate(FXP8),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     server = BatchedServer(model, ctx, params, slots=args.slots,
                            max_len=args.max_len)
     obs = attach_observer(server)

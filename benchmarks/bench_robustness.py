@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import sys
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineContext, FXP16, PrecisionPolicy
@@ -93,7 +92,7 @@ def _isolation_config(arch, args, *, speculative):
     cfg, model, params = load_model(arch, full_size=args.full_size,
                                     d_model=args.d_model)
     ctx = EngineContext(mode="carmen", policy=PrecisionPolicy.accurate(FXP16),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     bank = build_bank(params, "carmen", default_points(FXP16, hifi_fmt=None),
                       specs=model.specs())
     max_len = 16 + args.max_new + (3 if speculative else 0)
@@ -148,7 +147,7 @@ def _overload_config(args):
     cfg, model, params = load_model("olmo-1b", full_size=args.full_size,
                                     d_model=args.d_model)
     ctx = EngineContext(mode="carmen", policy=PrecisionPolicy.accurate(FXP16),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     max_len = 16 + args.max_new
 
     def serve(resilience):
@@ -228,7 +227,7 @@ def _degradation_config(args):
     cfg, model, params = load_model("olmo-1b", full_size=args.full_size,
                                     d_model=args.d_model)
     ctx = EngineContext(mode="carmen", policy=PrecisionPolicy.accurate(FXP16),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     bank = build_bank(params, "carmen", default_points(FXP16, hifi_fmt=None),
                       specs=model.specs())
     max_len = 16 + args.max_new

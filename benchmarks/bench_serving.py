@@ -44,7 +44,6 @@ import os
 import subprocess
 import sys
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineContext, FXP16, PrecisionPolicy
@@ -126,7 +125,7 @@ def _sharded_worker(args):
     max_len = 16 + args.max_new + args.draft_len
     cfg, model, params = load_model("olmo-1b", full_size=args.full_size,
                                     d_model=args.d_model)
-    ctx = EngineContext(mode="exact", compute_dtype=jnp.float32)
+    ctx = EngineContext(mode="exact", compute_dtype=cfg.compute_dtype)
     work = lambda: _workload(cfg, args.requests, max_new=args.max_new)
 
     none_srv = BatchedServer(model, ctx, params, slots=slots, max_len=max_len)
@@ -167,7 +166,19 @@ def _sharded_worker(args):
 
 def _sharded_sweep(args):
     """Fan the device-count sweep out to fresh subprocesses (the forced host
-    device count is locked at first jax init) and gate on the results."""
+    device count is locked at first jax init) and gate on the results.
+
+    The workers run on forced host (CPU) devices, so on a TPU host the sweep
+    refuses to start rather than quietly measure the CPU; a chip mesh is
+    served in one process (``python -m repro.launch.serve --mesh auto``)."""
+    import jax
+
+    if jax.devices()[0].platform == "tpu":
+        raise SystemExit(
+            "the sharded sweep runs one CPU worker process per device count; "
+            "on a TPU host serve the chip mesh in one process instead: "
+            "python -m repro.launch.serve --mesh auto ..."
+        )
     devices = [int(x) for x in args.devices.split(",")]
     passthrough = ["--_sharded-worker",
                    "--slots", str(args.slots),
@@ -277,7 +288,7 @@ def main(argv=None):
     for name, arch in CONFIG_ARCHS.items():
         cfg, model, params = load_model(arch, full_size=args.full_size,
                                         d_model=args.d_model)
-        ctx = EngineContext(mode="exact", compute_dtype=jnp.float32)
+        ctx = EngineContext(mode="exact", compute_dtype=cfg.compute_dtype)
         make = lambda burst: BatchedServer(model, ctx, params, slots=args.slots,
                                            max_len=max_len, burst=burst)
         record["configs"][name] = {
@@ -295,7 +306,7 @@ def main(argv=None):
     cfg, model, params = load_model("olmo-1b", full_size=args.full_size,
                                     d_model=args.d_model)
     ctx = EngineContext(mode="carmen", policy=PrecisionPolicy.accurate(FXP16),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     bank = build_bank(params, "carmen", default_points(FXP16, hifi_fmt=None),
                       specs=model.specs())
     make = lambda burst: BatchedServer(

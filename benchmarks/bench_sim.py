@@ -46,7 +46,6 @@ import json
 import os
 import sys
 
-import jax.numpy as jnp
 
 from repro.core import EngineContext, FXP8, PrecisionPolicy
 from repro.runtime import ControllerConfig, ModeController, build_bank, default_points
@@ -165,7 +164,7 @@ def main(argv=None):
     cfg, model, params = load_model(args.arch, full_size=args.full_size,
                                     d_model=args.d_model)
     ctx = EngineContext(mode="carmen", policy=PrecisionPolicy.accurate(FXP8),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     # the bank carries the calibration: controller, telemetry, and simulator
     # all price points with the same constants
     bank = build_bank(params, "carmen", default_points(FXP8, hifi_fmt=None),

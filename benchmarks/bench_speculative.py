@@ -24,7 +24,6 @@ the quantities the draft/verify split trades in:
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core import EngineContext, FXP8, FXP16, PrecisionPolicy
@@ -126,7 +125,7 @@ def main(argv=None):
     )
     draft_lens = [int(x) for x in args.draft_lens.split(",")]
     ctx = EngineContext(mode=bank.mode, policy=PrecisionPolicy.accurate(fmt),
-                        compute_dtype=jnp.float32)
+                        compute_dtype=cfg.compute_dtype)
     # one cache geometry for the whole sweep: the baseline is served once
     max_len = args.prompt_len + args.max_new + max(draft_lens) + 2
     ref_out, ref_dt = bench_accurate_only(

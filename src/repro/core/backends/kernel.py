@@ -17,6 +17,7 @@ that carried static formats in ``meta``.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .. import cordic
@@ -62,14 +63,19 @@ class KernelBackend(Backend):
 
         lp_af = ctx.layer_precision("af")
         fn = fused_dot_af if _use_fused(ctx, x.shape[-1]) else fused_dot_af_ref
+        # Barriers at both ends: the kernel is a fusion boundary, so the XLA
+        # chain must be one too. Fused into its neighbours it would change
+        # their code — a skipped bf16 round (XLA's excess precision), another
+        # accumulation order in the producing einsum — and the two paths
+        # would no longer give the same bits.
         out = fn(
-            x, w.data, w.point,
+            jax.lax.optimization_barrier(x), w.data, w.point,
             af_mode=af_mode,
             af_depth=int(lp_af.depth),
             af_fmt=lp_af.fmt,
             compute_round=ctx.compute_dtype != jnp.float32,
         )
-        return out.astype(ctx.compute_dtype)
+        return jax.lax.optimization_barrier(out.astype(ctx.compute_dtype))
 
     def dot(self, ctx, x, w, *, name: str = ""):
         if isinstance(w, PreparedWeight) and w.point is not None:

@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import activations as afs
 from repro.core.fxp import FxPFormat, dequantize, quantize, requantize
@@ -60,15 +61,6 @@ def _af_softmax_kernel(x_ref, out_ref, *, depth: int, fmt: FxPFormat):
     out_ref[...] = dequantize(requantize(out_raw, ifmt, fmt), fmt)
 
 
-def _smem_spec():
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    except ImportError:  # pragma: no cover
-        return pl.BlockSpec(memory_space=pl.ANY)
-
-
 @functools.partial(jax.jit, static_argnames=("depth", "fmt", "bm", "bn", "interpret"))
 def af_elementwise(
     x,
@@ -88,7 +80,7 @@ def af_elementwise(
         functools.partial(_af_elementwise_kernel, depth=depth, fmt=fmt),
         grid=(m // bm, n // bn),
         in_specs=[
-            _smem_spec(),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),

@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.fxp import FXP8, FxPFormat
 
@@ -51,32 +52,19 @@ def _pad_to(x, rows: int, cols: int):
 
 
 def _grid_call(kernel_fn, grid, bm, kp, bn, out_shape, interpret):
-    """Build the pallas_call, preferring the scalar-prefetch grid spec."""
-    in_specs = [
-        pl.BlockSpec((bm, kp), lambda i, j, *_: (i, 0)),
-        pl.BlockSpec((kp, bn), lambda i, j, *_: (0, j)),
-    ]
-    out_specs = pl.BlockSpec((bm, bn), lambda i, j, *_: (i, j))
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=grid,
-            in_specs=in_specs, out_specs=out_specs,
-        )
-        return pl.pallas_call(
-            kernel_fn, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
-        )
-    except ImportError:  # pragma: no cover - non-TPU pallas builds
-        return pl.pallas_call(
-            kernel_fn,
-            grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + in_specs,
-            out_specs=out_specs,
-            out_shape=out_shape,
-            interpret=interpret,
-        )
+    """The pallas_call, with the params vector as a scalar-prefetch operand."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=[
+            pl.BlockSpec((bm, kp), lambda i, j, *_: (i, 0)),
+            pl.BlockSpec((kp, bn), lambda i, j, *_: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, *_: (i, j)),
+    )
+    return pl.pallas_call(
+        kernel_fn, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret,
+    )
 
 
 @functools.partial(

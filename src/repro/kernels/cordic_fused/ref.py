@@ -1,9 +1,10 @@
 """Pure-XLA reference for the fused dot+AF chain.
 
-Runs the *identical* integer-dot computation as the Pallas kernel — same
-quantization, same int32 ``dot_general``, same descale association, same
-activation epilogue — so it is bitwise equal to the kernel in interpret mode
-and on TPU.  It doubles as the dispatch fallback whenever the fused kernel is
+Runs the same integer-dot computation as the Pallas kernel — same
+quantization, the same integer dot (here one plain int32 ``dot_general``; the
+kernel feeds the MXU exact int8 digits of it), same descale, same activation
+epilogue — so it is bitwise equal to the kernel in interpret mode and on
+TPU.  It doubles as the dispatch fallback whenever the fused kernel is
 unavailable (mesh-sharded params, oversized K) and as the oracle in the
 parity tests.
 """
@@ -12,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .kernel import P_WFRAC, P_XFRAC, P_XQMAX, P_XQMIN, af_epilogue
+from .kernel import P_WFRAC, P_XFRAC, P_XQMAX, P_XQMIN, af_epilogue, pow2
 
 
 def fused_dot_af_ref(x, w, point, *, af_mode, af_depth, af_fmt, compute_round):
@@ -24,17 +25,13 @@ def fused_dot_af_ref(x, w, point, *, af_mode, af_depth, af_fmt, compute_round):
     w_frac = point[P_WFRAC]
 
     xq = jnp.clip(
-        jnp.round(x.astype(jnp.float32) * jnp.exp2(x_frac.astype(jnp.float32))),
-        qmin, qmax,
+        jnp.round(x.astype(jnp.float32) * pow2(x_frac)), qmin, qmax
     ).astype(jnp.int32)
-    wq = jnp.round(
-        w.astype(jnp.float32) * jnp.exp2(w_frac.astype(jnp.float32))
-    ).astype(jnp.int32)
+    wq = jnp.round(w.astype(jnp.float32) * pow2(w_frac)).astype(jnp.int32)
 
     acc = jax.lax.dot_general(
         xq, wq, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    h = (acc.astype(jnp.float32) * jnp.exp2(-x_frac.astype(jnp.float32))
-         ) * jnp.exp2(-w_frac.astype(jnp.float32))
+    h = acc.astype(jnp.float32) * pow2(-(x_frac + w_frac))
     return af_epilogue(h, af_mode, af_depth, af_fmt, compute_round)

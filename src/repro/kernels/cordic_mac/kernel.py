@@ -27,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -103,16 +104,7 @@ def mac_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        scratch_shapes=[pltpu_vmem((bm, bn), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
     )(x_q, w_q, x_scale, w_scale)
 
-
-def pltpu_vmem(shape, dtype):
-    """VMEM scratch allocation (TPU backend); plain scratch in interpret mode."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except ImportError:  # pragma: no cover - CPU-only environments
-        return pl.MemorySpace.ANY(shape, dtype)

@@ -16,8 +16,14 @@ def _interpret_default() -> bool:
 def gqa_decode_attention(q, ck, cv, positions, *, scale: float,
                          interpret: bool | None = None):
     """Cache-decode GQA attention: q (B, S, H, hd) against slot caches
-    ck/cv (B, T, KV, hd) with per-query positions (B, S)."""
+    ck/cv (B, T, KV, hd) with per-query positions (B, S). Compiled for a TPU
+    it needs lane-aligned heads, ``hd % 128 == 0``."""
     interpret = _interpret_default() if interpret is None else interpret
+    if not interpret and q.shape[-1] % 128:
+        raise ValueError(
+            f"the compiled GQA decode kernel needs head_dim % 128 == 0, got "
+            f"{q.shape[-1]}; serve this model with attn_impl='xla'"
+        )
     groups = q.shape[2] // ck.shape[2]
     return _k.gqa_decode(q, ck, cv, positions, groups=groups, scale=scale,
                          interpret=interpret)

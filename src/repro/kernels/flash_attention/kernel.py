@@ -25,6 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -93,8 +94,6 @@ def flash_attention_bhsd(
     grid = (bh, sq // bq, n_k)
     scale = 1.0 / math.sqrt(d)
 
-    from repro.kernels.cordic_mac.kernel import pltpu_vmem
-
     return pl.pallas_call(
         functools.partial(
             _flash_kernel, n_k=n_k, bq=bq, bk=bk, causal=causal, scale=scale
@@ -108,9 +107,9 @@ def flash_attention_bhsd(
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu_vmem((bq, d), jnp.float32),
-            pltpu_vmem((bq, 1), jnp.float32),
-            pltpu_vmem((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)

@@ -27,8 +27,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.cordic_mac.kernel import pltpu_vmem
 
 NEG_INF = -1e30
 
@@ -109,9 +109,9 @@ def mla_flash(q_cat, k_cat, v, *, causal: bool = True, bq: int = 128, bk: int = 
         out_specs=pl.BlockSpec((1, bq, bh, dv), lambda bb, hh, qq, kk: (bb, qq, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, dv), q_cat.dtype),
         scratch_shapes=[
-            pltpu_vmem((bq, bh, dv), jnp.float32),
-            pltpu_vmem((bq, bh, 1), jnp.float32),
-            pltpu_vmem((bq, bh, 1), jnp.float32),
+            pltpu.VMEM((bq, bh, dv), jnp.float32),
+            pltpu.VMEM((bq, bh, 1), jnp.float32),
+            pltpu.VMEM((bq, bh, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q_cat, k_cat, v)

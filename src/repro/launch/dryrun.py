@@ -157,7 +157,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, mode: str = "exact",
     t0 = time.time()
     try:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-        with mesh:
+        with jax.set_mesh(mesh):
             step, args, in_sh, out_sh = build_cell(
                 arch, shape_name, mesh, mode, attn=attn, pad_heads_to=pad_heads_to,
                 tp_bf16=tp_bf16, microbatches=microbatches, prepared=prepared,

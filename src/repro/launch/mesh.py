@@ -2,18 +2,30 @@
 
 A FUNCTION, not a module-level constant, so importing this module never touches
 jax device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh here has ``Auto`` axes: the repo places arrays with
+``NamedSharding`` and lets GSPMD propagate the rest (``partition.constrain``
+pins activation layouts), which ``Explicit`` axes — ``jax.make_mesh``'s
+default — do not allow.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """A device mesh with ``Auto`` axes (see the module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: Optional[int] = None):
@@ -34,4 +46,4 @@ def make_host_mesh(*, model: Optional[int] = None):
         raise ValueError(
             f"model={model} does not divide the {n} local devices"
         )
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

@@ -55,22 +55,67 @@ become submit-relative. Two live drivers ride the same scheduler::
 
     # minimal HTTP service: POST /generate {"prompt": [...], "max_new": N}
     ... --http-port 8080
+
+Compute runs in the config's dtype (bfloat16 at published widths, float32
+under ``--reduced``). Compiled programs persist in ``$JAX_COMPILATION_CACHE_DIR``
+when it is set, else in ``.jax_cache/`` at the root of the checkout.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import pathlib
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config, reduced as reduce_cfg
 from repro.core import FXP8, FXP16, EngineContext, PrecisionPolicy, assign_depths
 from repro.models import get_model
 from repro.serve.engine import BatchedServer, Request
+
+
+CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Persist compiled programs: in ``$JAX_COMPILATION_CACHE_DIR`` when set
+    (jax reads it itself; nothing else is set), else in ``.jax_cache/`` at
+    the root of the checkout — a fixed path, since the path is part of the
+    cache key. Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def prompt_lengths(spec: str):
+    """``--prompt-len`` as ``(lo, hi)``: ``"N"`` or a seeded range ``"LO-HI"``."""
+    lo, _, hi = spec.partition("-")
+    lo, hi = int(lo), int(hi or lo)
+    if not 0 <= lo <= hi:
+        raise ValueError(f"want N or LO-HI with LO <= HI, got {spec!r}")
+    return lo, hi
+
+
+def synthetic_requests(args, vocab_size: int):
+    """The CLI's seeded workload: ``--requests`` prompts of ``--prompt-len``
+    tokens (uniform in the range when one is given)."""
+    lo, hi = args.prompt_len
+    rng = np.random.default_rng(0)
+    reqs = []
+    for i in range(args.requests):
+        n = lo if lo == hi else int(rng.integers(lo, hi + 1))
+        reqs.append(Request(
+            i, rng.integers(0, vocab_size, n).astype(np.int32),
+            args.max_new, temperature=args.temperature,
+            seed=None if args.seed is None else args.seed + i,
+        ))
+    return reqs
 
 
 def resolve_policy(args, model, params, fmt) -> PrecisionPolicy:
@@ -81,7 +126,8 @@ def resolve_policy(args, model, params, fmt) -> PrecisionPolicy:
         from repro.runtime import calibration_scan
 
         rng = np.random.default_rng(0)
-        tokens = rng.integers(0, model.cfg.vocab_size, (2, max(args.prompt_len, 8)))
+        tokens = rng.integers(0, model.cfg.vocab_size,
+                              (2, max(args.prompt_len[1], 8)))
         sens = calibration_scan(model, params, tokens, fmt=fmt, mode=args.mode)
         policy = assign_depths(
             sens, fmt=fmt, cycle_reduction_target=args.cycle_reduction
@@ -292,13 +338,18 @@ def _serve_frontend(args, server, reqs):
 
 
 def main(argv=None):
+    """Serve the CLI's workload; returns ``(server, results)`` (rid ->
+    generated tokens) for programmatic callers."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), default="olmo-1b")
     ap.add_argument("--reduced", action="store_true")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--prompt-len", type=int, default=8)
+    ap.add_argument("--prompt-len", type=prompt_lengths, default="8",
+                    metavar="N|LO-HI",
+                    help="prompt tokens per request, or a range to draw "
+                         "each request's length from (seeded)")
     ap.add_argument("--mode", choices=["exact", "carmen", "int8", "kernel"], default="exact")
     ap.add_argument("--per-call", action="store_true",
                     help="skip prepare_params: re-quantize weights every step "
@@ -419,16 +470,17 @@ def main(argv=None):
                               "POST /generate with a JSON request body; "
                               "Ctrl-C to stop")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     mesh = None
     if args.mesh:
-        from repro.launch.mesh import make_host_mesh
+        from repro.launch.mesh import make_host_mesh, make_mesh
 
         if args.mesh == "auto":
             mesh = make_host_mesh()
         else:
             data, model_ext = (int(x) for x in args.mesh.split(","))
-            mesh = jax.make_mesh((data, model_ext), ("data", "model"))
+            mesh = make_mesh((data, model_ext), ("data", "model"))
         print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
               f"over {mesh.devices.size} devices")
 
@@ -440,11 +492,12 @@ def main(argv=None):
     fmt = FXP16 if args.fxp16 else FXP8
 
     if args.mode == "exact":
-        ctx = EngineContext(mode="exact", compute_dtype=jnp.float32)
+        ctx = EngineContext(mode="exact", compute_dtype=cfg.compute_dtype)
         policy = None
     else:
         policy = resolve_policy(args, model, params, fmt)
-        ctx = EngineContext(mode=args.mode, policy=policy, compute_dtype=jnp.float32)
+        ctx = EngineContext(mode=args.mode, policy=policy,
+                            compute_dtype=cfg.compute_dtype)
 
     controller = None
     bank = None
@@ -515,7 +568,7 @@ def main(argv=None):
 
     server = BatchedServer(
         model, ctx, params, slots=args.slots,
-        max_len=args.prompt_len + args.max_new
+        max_len=args.prompt_len[1] + args.max_new
         + (args.draft_len if args.speculative else 0) + 2,
         burst=args.burst,
         prepare_weights=not args.per_call,
@@ -525,6 +578,9 @@ def main(argv=None):
         mesh=mesh,
         resilience=resilience,
     )
+    # the server holds the prepared tree (or the bank); the raw float tree
+    # is dead weight on the device from here on
+    del params
     if server.shardings is not None:
         from repro.sharding.partition import serving_sharding_report
 
@@ -538,15 +594,7 @@ def main(argv=None):
         # raises, so crashed-run traces stay replayable
         observer = ServingObserver(trace=want_trace, trace_sink=args.trace_out)
         server.observer = observer
-    rng = np.random.default_rng(0)
-    reqs = [
-        Request(
-            i, rng.integers(0, cfg.vocab_size, args.prompt_len).astype(np.int32),
-            args.max_new, temperature=args.temperature,
-            seed=None if args.seed is None else args.seed + i,
-        )
-        for i in range(args.requests)
-    ]
+    reqs = synthetic_requests(args, cfg.vocab_size)
     use_frontend = args.frontend or args.stdin_requests or args.http_port
     if use_frontend and mesh is not None:
         raise SystemExit("the streaming frontend is single-device for now: "
@@ -615,7 +663,7 @@ def main(argv=None):
             print(f"chrome trace written to {args.chrome_trace}")
     for rid in sorted(results):
         print(f"  req {rid}: {results[rid][:8]}...")
-    return results
+    return server, results
 
 
 if __name__ == "__main__":

@@ -65,7 +65,7 @@ def main(argv=None):
 
     mesh = make_production_mesh() if args.production_mesh else make_host_mesh()
     pipe = TokenPipeline(cfg, args.seq, args.batch)
-    with mesh:
+    with jax.set_mesh(mesh):
         specs = model.specs()
         param_sh, _ = partition.param_shardings(specs, mesh)
         params = jax.jit(

@@ -377,21 +377,6 @@ def _dispatch_indices(expert_idx, num_experts: int, capacity: int):
     return gather_idx, valid, rank.reshape(s, k)
 
 
-def _get_shard_map():
-    """(shard_map, relax-kwargs, physical mesh) across jax versions."""
-    try:
-        from jax import shard_map as sm
-
-        relax = {"check_vma": False}
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map as sm
-
-        relax = {"check_rep": False}
-    from jax._src import mesh as mesh_lib
-
-    return sm, relax, mesh_lib.thread_resources.env.physical_mesh
-
-
 def _combine_scatter(yw, token_of_choice, s: int, d: int):
     """Combine expert-slot outputs into per-token sums.
 
@@ -420,21 +405,19 @@ def _combine_scatter(yw, token_of_choice, s: int, d: int):
     if "model" in axes and e % max(sizes.get("model", 1), 1) == 0:
         from jax.sharding import PartitionSpec as _P
 
-        _shard_map, _relax, phys = _get_shard_map()
         batch_axes = tuple(a for a in BATCH_AXES if a in axes)
         import numpy as _np
 
         bext = int(_np.prod([sizes.get(a, 1) for a in batch_axes])) if batch_axes else 1
         bspec = batch_axes if (batch_axes and b % bext == 0) else None
-        return _shard_map(
+        return jax.shard_map(
             local,
-            mesh=phys,
             in_specs=(
                 _P(bspec, "model", None, None),
                 _P(bspec, "model", None),
             ),
             out_specs=_P(bspec, None, None),
-            **_relax,
+            check_vma=False,
         )(yw, token_of_choice)
     bb = yw.shape[0]
     return (
@@ -487,8 +470,8 @@ def moe_ffn(p, x, cfg: ModelConfig, ctx: EngineContext, *, name,
         # single-device by construction, and O(S*K) int work is free.
         from jax.sharding import PartitionSpec as _P
 
-        sm, relax, phys = _get_shard_map()
-        plan = sm(plan_fn, mesh=phys, in_specs=_P(), out_specs=_P(), **relax)(top_i)
+        plan = jax.shard_map(plan_fn, in_specs=_P(), out_specs=_P(),
+                             check_vma=False)(top_i)
     else:
         plan = plan_fn(top_i)
     gather_idx, valid, rank = plan  # (B,E,C), (B,E,C), (B,S,K)

@@ -126,8 +126,9 @@ def place_bank(bank: MultiPointBank, mesh, specs=None) -> MultiPointBank:
     hold references to the bank), returns the bank. Idempotent: re-placing an
     already-placed bank is a no-op device_put.
     """
-    from repro.sharding.partition import prepared_shardings
+    from repro.sharding.partition import prepared_shardings, require_auto_axes
 
+    require_auto_axes(mesh)
     if specs is None:
         raise ValueError("place_bank needs the model's param specs "
                          "(model.specs()) to derive shardings")

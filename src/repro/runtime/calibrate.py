@@ -44,7 +44,8 @@ def calibration_scan(
     batch = {"tokens": jnp.asarray(tokens, jnp.int32)}
 
     def logits_at(policy: PrecisionPolicy) -> np.ndarray:
-        ctx = EngineContext(mode=mode, policy=policy, compute_dtype=jnp.float32)
+        ctx = EngineContext(mode=mode, policy=policy,
+                            compute_dtype=model.cfg.compute_dtype)
         out, _ = model.forward(params, batch, ctx)
         return np.asarray(out, np.float32)
 

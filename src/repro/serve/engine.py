@@ -97,6 +97,17 @@ from .kvcache import bucket_length, scatter_rows, with_cache_positions
 _BATCHED_PREFILL_FAMILIES = ("dense", "vlm", "moe")
 
 
+def exact_rounding(ctx: EngineContext) -> Optional[Dict]:
+    """Compile options of a serving program. Kernel mode compiles without
+    excess precision: XLA may otherwise keep f32 intermediates inside a
+    fusion, and which ones depends on what the fusion holds, so the fused
+    kernel's program and the XLA chain's (``fused="off"``, or any mesh)
+    could round apart and greedy streams would differ. Only kernel mode has
+    two implementations of one dot that must agree bit for bit; the other
+    modes keep XLA's default."""
+    return {"xla_allow_excess_precision": False} if ctx.mode == "kernel" else None
+
+
 def make_decode_sample_step(model: ModelApi, ctx: EngineContext, *,
                             temperature: float = 0.0):
     """Decode + on-device sampling: only (B, 1) ids leave the device."""
@@ -586,7 +597,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             )
         self.prefill = jax.jit(
             prefill_factory(self.model, self.ctx, self.max_len),
-            donate_argnums=(1, 2),
+            donate_argnums=(1, 2), compiler_options=exact_rounding(self.ctx),
             **prefill_sharding_kwargs,
         )
 
@@ -1048,18 +1059,23 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             return None
         from repro.launch import hlo_analysis
 
+        costs = hlo_analysis.analyze(self.compiled_burst_text())
+        return {
+            "collective_bytes": costs.collective_bytes,
+            "collective_by_kind": costs.collective_by_kind,
+        }
+
+    def compiled_burst_text(self) -> str:
+        """Optimized HLO text of the all-greedy decode burst as compiled for
+        the current serving tree, cache and slot state (compiles it if it
+        has not run)."""
         with self._scope():
-            hlo = (
+            return (
                 self.decode_burst(False)
                 .lower(self._serving_tree(), self.cache, self._state)
                 .compile()
                 .as_text()
             )
-        costs = hlo_analysis.analyze(hlo)
-        return {
-            "collective_bytes": costs.collective_bytes,
-            "collective_by_kind": costs.collective_by_kind,
-        }
 
     def _observe(self, point, tokens, steps, queue_depth, free_slots,
                  min_margin, deadline_misses=0, shed=0):
@@ -1079,8 +1095,9 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
 
     def _scope(self):
         """Ambient context for the jitted hot-path calls. A no-op without a
-        mesh; with one it (a) installs the mesh so the model's activation
-        constraints (``partition.constrain``) bind to it at trace time and
+        mesh; with one it (a) sets the mesh (``jax.set_mesh``) so the model's
+        activation constraints (``partition.constrain``) bind to it at trace
+        time and
         (b) switches to partitionable threefry — the sharding-invariant PRNG
         mode, so SAMPLED streams are identical across mesh shapes (the legacy
         PRNG generates different bits when the vocab axis is sharded; greedy
@@ -1090,7 +1107,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             return contextlib.nullcontext()
         stack = contextlib.ExitStack()
         stack.enter_context(jax.threefry_partitionable(True))
-        stack.enter_context(self.mesh)
+        stack.enter_context(jax.set_mesh(self.mesh))
         return stack
 
     def chunk_fns(self):
@@ -1109,10 +1126,12 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
                     "frontend rejects mesh= (ROADMAP: sharded streaming)"
                 )
             self._chunk_fns = (
-                jax.jit(factory(self.model, self.ctx), donate_argnums=(1, 2)),
+                jax.jit(factory(self.model, self.ctx), donate_argnums=(1, 2),
+                        compiler_options=exact_rounding(self.ctx)),
                 # the row is an input-only buffer here (scattered into the
                 # slot cache, never returned) — donating it would just warn
-                jax.jit(make_chunk_admit(), donate_argnums=(0, 1)),
+                jax.jit(make_chunk_admit(), donate_argnums=(0, 1),
+                        compiler_options=exact_rounding(self.ctx)),
             )
         return self._chunk_fns
 
@@ -1139,7 +1158,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             self._burst_fns[sampled] = jax.jit(
                 make_decode_burst(self.model, self.ctx, self.burst,
                                   sampled=sampled, logit_limit=limit),
-                donate_argnums=(1, 2),
+                donate_argnums=(1, 2), compiler_options=exact_rounding(self.ctx),
                 **sharding_kwargs,
             )
         return self._burst_fns[sampled]

@@ -179,6 +179,7 @@ class ServingShardings:
 def serving_shardings(mesh: Mesh, *, params, cache, state, specs, cfg,
                       max_len: Optional[int] = None) -> ServingShardings:
     """Build the full serving placement bundle for ``BatchedServer(mesh=...)``."""
+    require_auto_axes(mesh)
     report: list = []
     params_sh = prepared_shardings(params, specs, mesh, report=report)
     cache_sh = cache_shardings(cache, mesh, cfg, row_axis_len=max_len)
@@ -241,23 +242,26 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def current_mesh_axes() -> Tuple[str, ...]:
-    """Axis names of the ambient mesh (jax.set_mesh or `with mesh:`), or ()."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return tuple(am.axis_names)
-    except Exception:
-        pass
-    try:
-        from jax._src import mesh as mesh_lib
+def require_auto_axes(mesh: Mesh) -> None:
+    """Raise unless every axis of ``mesh`` is ``Auto``: placement here leans
+    on GSPMD propagation and ``with_sharding_constraint``, which ``Explicit``
+    axes (``jax.make_mesh``'s default) refuse."""
+    if any(t != jax.sharding.AxisType.Auto for t in mesh.axis_types):
+        raise ValueError(
+            f"mesh axes {dict(zip(mesh.axis_names, mesh.axis_types))} must "
+            "all be Auto: build the mesh with repro.launch.mesh.make_mesh"
+        )
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if not m.empty:
-            return tuple(m.axis_names)
-    except Exception:
-        pass
-    return ()
+
+def mesh_axis_sizes() -> Dict[str, int]:
+    """Axis name -> extent of the ambient mesh (``jax.set_mesh``), or {}."""
+    am = jax.sharding.get_abstract_mesh()
+    return {} if am.empty else dict(am.shape)
+
+
+def current_mesh_axes() -> Tuple[str, ...]:
+    """Axis names of the ambient mesh (``jax.set_mesh``), or ()."""
+    return tuple(mesh_axis_sizes())
 
 
 def constrain(x, *entries):
@@ -270,16 +274,10 @@ def constrain(x, *entries):
     embedding gather — observed: a TP-only program doing 32x redundant work;
     see EXPERIMENTS.md §Dry-run).
     """
-    axes = current_mesh_axes()
+    sizes = mesh_axis_sizes()
+    axes = tuple(sizes)
     if not axes:
         return x
-    from jax._src import mesh as mesh_lib
-
-    try:
-        phys = mesh_lib.thread_resources.env.physical_mesh
-        sizes = dict(zip(phys.axis_names, phys.devices.shape)) if not phys.empty else {}
-    except Exception:
-        sizes = {}
     spec = []
     used: set = set()
     for i, e in enumerate(entries):
@@ -307,18 +305,6 @@ def constrain(x, *entries):
     if all(s is None for s in spec):
         return x
     return jax.lax.with_sharding_constraint(x, P(*spec))
-
-
-def mesh_axis_sizes() -> Dict[str, int]:
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        if not m.empty:
-            return dict(zip(m.axis_names, m.devices.shape))
-    except Exception:
-        pass
-    return {}
 
 
 def use_2d_ep(num_experts: int) -> bool:

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from repro.core import EngineContext
 from repro.models import ModelApi
 from repro.runtime.bank import MultiPointBank
+from repro.serve.engine import exact_rounding
 
 from .config import SpecConfig
 from .decoding import make_draft_loop, make_verify_step
@@ -67,11 +68,11 @@ class SpeculativeDecoder:
             )
         self.draft_loop = jax.jit(
             make_draft_loop(model, ctx, self.cfg.draft_len), donate_argnums=(2,),
-            **draft_kwargs,
+            compiler_options=exact_rounding(ctx), **draft_kwargs,
         )
         self.verify = jax.jit(
             make_verify_step(model, ctx, self.cfg.draft_len), donate_argnums=(4,),
-            **verify_kwargs,
+            compiler_options=exact_rounding(ctx), **verify_kwargs,
         )
         self.telemetry = SpecTelemetry.for_bank(bank, self.cfg.draft_len)
         # optional repro.obs.ServingObserver: draft/verify dispatch spans and

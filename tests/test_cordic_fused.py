@@ -25,7 +25,7 @@ from repro.configs import get_config, reduced
 from repro.core import EngineContext, PrecisionPolicy
 from repro.core.backends import prepare_params
 from repro.core.backends.base import PreparedWeight
-from repro.core.fxp import FXP8, FXP16
+from repro.core.fxp import FXP8, FXP16, FXP16_UNIT
 from repro.core import cordic
 from repro.kernels.cordic_fused import (
     FUSED_AFS,
@@ -286,3 +286,78 @@ def test_serving_speculative_fused_parity():
         outs[fused] = srv.run(_requests(cfg, 2, max_new=6))
         assert srv.spec_telemetry.summary()["emitted"] > 0
     assert outs["on"] == outs["off"]
+
+
+# ---------------------------------------------------------------------------
+# int8 MXU operands: the digit split is the int32 dot
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("bound", [128, 1 << 15], ids=["int8", "int16"])
+def test_int_dot_equals_int32_dot(bound):
+    """int8 operands directly (narrow) or as three base-2**7 digits (wide)
+    reproduce the int32 dot exactly — modulo 2**32 where it wraps —
+    including the range extremes."""
+    from repro.kernels.cordic_fused.kernel import int_dot
+
+    rng = np.random.default_rng(3)
+    x = rng.integers(-bound, bound, size=(8, 256)).astype(np.int32)
+    w = rng.integers(-bound, bound, size=(256, 128)).astype(np.int32)
+    x[0, :] = -bound
+    x[1, :] = bound - 1
+    w[:, 0] = bound - 1
+    w[:, 1] = -bound
+    want = jax.lax.dot_general(jnp.asarray(x), jnp.asarray(w),
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+    narrow = bound == 128
+    got = jax.jit(int_dot, static_argnums=2)(jnp.asarray(x), jnp.asarray(w),
+                                             narrow)
+    assert jnp.array_equal(got, want)
+    if narrow:  # the digit path covers the narrow range too
+        assert jnp.array_equal(int_dot(jnp.asarray(x), jnp.asarray(w), False),
+                               want)
+
+
+def test_fused_kernel_saturated_fxp16_matches_ref():
+    """FXP16 activations at the clip bounds against full-scale weight grids:
+    the wide (digit) kernel path equals the int32 reference bitwise."""
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.choice([-1e3, 1e3], size=(8, 64)).astype(np.float32))
+    w = jnp.asarray(rng.choice([-1.99, 1.99], size=(64, 128)).astype(np.float32))
+    sd = cordic.signed_digit_round(w, 15, FXP16_UNIT)
+    point = make_point(15, FXP16, FXP16_UNIT)
+    got = fused_dot_af(x, sd, point, af_mode="identity", interpret=True)
+    want = fused_dot_af_ref(x, sd, point, af_mode="identity", af_depth=8,
+                            af_fmt=FXP8, compute_round=False)
+    assert jnp.array_equal(got, want)
+
+
+def test_make_point_rejects_formats_beyond_the_digit_split():
+    from repro.core.fxp import FxPFormat
+
+    with pytest.raises(ValueError, match="16 bits"):
+        make_point(8, FxPFormat(24, 12), FXP16_UNIT)
+    with pytest.raises(ValueError, match="fraction bits"):
+        make_point(8, FXP16, FxPFormat(16, 15))
+
+
+# ---------------------------------------------------------------------------
+# the epilogue's bf16 round, built from integer ops
+# ---------------------------------------------------------------------------
+
+
+def test_bit_rounding_equals_bf16_convert():
+    """``round_to_bf16`` (f32 -> nearest bf16, ties to even) equals the
+    plain convert on finite values, ties and overflow to infinity included."""
+    from repro.kernels.cordic_fused.kernel import round_to_bf16
+
+    rng = np.random.default_rng(5)
+    v = (rng.standard_normal(1 << 16) * 10.0 ** rng.integers(-30, 30, 1 << 16)
+         ).astype(np.float32)
+    ties = np.array([1 + 2.0 ** -8, 1 + 3 * 2.0 ** -8, -(1 + 2.0 ** -8)],
+                    np.float32)  # halfway between two bf16 values
+    v = jnp.asarray(np.concatenate([v, ties, [0.0, -0.0, 3.4e38, -3.4e38]]
+                                   ).astype(np.float32))
+    want = v.astype(jnp.bfloat16)
+    assert jnp.array_equal(round_to_bf16(v), want.astype(jnp.float32))

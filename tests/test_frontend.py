@@ -403,7 +403,9 @@ def test_submit_requires_open_and_close_is_final(olmo):
 
 def test_mesh_server_rejected(olmo):
     cfg, model, params = olmo
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     server = BatchedServer(model, EXACT, params, slots=1, max_len=32,
                            burst=2, mesh=mesh)
     with pytest.raises(ValueError, match="single-device"):

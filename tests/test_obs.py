@@ -278,7 +278,9 @@ def test_observer_bit_identical_speculative(olmo):
 
 def test_observer_bit_identical_mesh(olmo):
     cfg, model, params = olmo
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
     ref, out, watched = _run_pair(model, EXACT, params, cfg, burst=4, mesh=mesh)
     assert out == ref
     # the mesh cost block is available for the trace header

@@ -90,7 +90,9 @@ def _isolation_case(arch, *, spec=False, mesh_shape=None, bank=None,
                     controller_factory=None):
     """Run fault-free vs one-slot-NaN and assert the isolation contract."""
     cfg, model, params = _setup(arch)
-    mesh = (jax.make_mesh(mesh_shape, ("data", "model"))
+    from repro.launch.mesh import make_mesh
+
+    mesh = (make_mesh(mesh_shape, ("data", "model"))
             if mesh_shape is not None else None)
     kw = dict(slots=4, max_len=64, burst=4, mesh=mesh,
               resilience=ResilienceConfig())

@@ -27,7 +27,7 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.core import EngineContext, FXP16, PrecisionPolicy
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import get_model
 from repro.serve.engine import BatchedServer, Request
 from repro.sharding import partition
@@ -43,7 +43,7 @@ def _mesh(shape):
             f"{shape[0]}x{shape[1]} mesh needs {shape[0] * shape[1]} host "
             "devices (XLA_FLAGS=--xla_force_host_platform_device_count=8)"
         )
-    return jax.make_mesh(shape, ("data", "model"))
+    return make_mesh(shape, ("data", "model"))
 
 
 def _setup(arch):
@@ -165,7 +165,7 @@ def test_sampled_streams_identical_across_mesh_shapes(olmo):
     for shape in MESH_SHAPES:
         if NDEV < shape[0] * shape[1]:
             continue
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         outs[shape] = BatchedServer(
             model, EXACT, params, slots=4, max_len=32, burst=4, mesh=mesh,
         ).run(_requests(cfg, max_new=8, temperature=1.3))
@@ -174,7 +174,7 @@ def test_sampled_streams_identical_across_mesh_shapes(olmo):
     assert all(o == first for o in outs.values())
     # sanity: the sampled stream actually diverges from greedy
     greedy = BatchedServer(model, EXACT, params, slots=4, max_len=32, burst=4,
-                           mesh=jax.make_mesh((1, 1), ("data", "model")),
+                           mesh=make_mesh((1, 1), ("data", "model")),
                            ).run(_requests(cfg, max_new=8))
     assert first != greedy
 
@@ -197,7 +197,7 @@ def test_cache_and_state_placement(olmo):
     mesh = _mesh((2, 2))
     srv = BatchedServer(model, EXACT, params, slots=4, max_len=32, burst=4,
                         mesh=mesh)
-    assert srv._state["tok"].sharding.spec[0] == ("data",)
+    assert srv._state["tok"].sharding.spec[0] == "data"
     s_axis_sharded = []
     for leaf in jax.tree.leaves(srv.cache):
         spec = tuple(leaf.sharding.spec)
@@ -263,7 +263,8 @@ def test_serving_sharding_report(olmo):
     rep = partition.serving_sharding_report(srv.shardings)
     assert rep["mesh"] == {"data": 2, "model": 2}
     assert rep["params"]["sharded"] >= 1
-    assert set(rep) == {"mesh", "dropped", "params", "cache", "state"}
+    assert set(rep) == {"mesh", "devices", "dropped", "params", "cache",
+                        "state"}
     for d in rep["dropped"]:  # every dropped rule names a non-dividing dim
         assert d["dim"] % d["extent"] != 0
     import json
