@@ -40,6 +40,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src
 
 import jax  # noqa: E402
 
+from repro.obs.trace import compile_counter  # noqa: E402
+
 ARCH = "olmo-1b"
 REQUESTS, SLOTS, MAX_NEW, BURST = 8, 4, 32, 8
 PROMPTS = "64-256"
@@ -47,25 +49,6 @@ PROMPTS = "64-256"
 # all land in the 256-row prefill bucket: one prefill program and two bursts
 # per run, as each second on a four-chip host costs four
 MESH_REQUESTS, MESH_MAX_NEW, MESH_PROMPTS = 4, 16, "129-256"
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-
-
-class CompileClock:
-    """Seconds JAX spent in backend compiles (cache loads included), read
-    from its monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event, duration, **_):
-        if event == BACKEND_COMPILE_EVENT:
-            self.seconds += duration
-
-    def lap(self) -> float:
-        out, self.seconds = self.seconds, 0.0
-        return out
-
 
 def serve_argv(mode: str, *, reduced: bool = False, frontend: bool = False,
                mesh: str | None = None, prompts: str = PROMPTS,
@@ -211,11 +194,15 @@ def main(argv=None) -> int:
     cache = serve.use_compile_cache()
     print(f"device_kind={dev.device_kind} platform={dev.platform} "
           f"count={len(devices)} compile_cache={cache}", flush=True)
-    clock = CompileClock()
+    compiles = compile_counter()
+    lapped = compiles.seconds
 
     def report(name, rec):
+        # seconds of the phase's backend compiles (cache loads included)
+        nonlocal lapped
         rec = {k: v for k, v in rec.items() if k not in ("run", "frontend")}
-        rec.update(compile_s=clock.lap(), **_memory(dev))
+        rec.update(compile_s=compiles.seconds - lapped, **_memory(dev))
+        lapped = compiles.seconds
         print(f"phase {name}: {json.dumps(rec)}", flush=True)
 
     if args.four_chips:

@@ -113,6 +113,7 @@ class CarmenBackend(Backend):
              ("x_fmt", (lp.fmt.bits, lp.fmt.frac))),
         )
 
+    @jax.named_scope("dot.carmen")
     def dot(self, ctx, x, w, *, name: str = ""):
         shape = x.shape[:-1] + (w.shape[-1],)
         x2 = x.reshape(-1, x.shape[-1])

@@ -1,6 +1,7 @@
 """Exact backend: FP32/bf16 matmul — the paper's FP32 baseline."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from .base import Backend, PreparedWeight
@@ -11,6 +12,7 @@ __all__ = ["ExactBackend"]
 class ExactBackend(Backend):
     name = "exact"
 
+    @jax.named_scope("dot.exact")
     def dot(self, ctx, x, w, *, name: str = ""):
         if isinstance(w, PreparedWeight):
             w = w.data

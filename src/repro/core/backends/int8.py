@@ -97,6 +97,7 @@ class Int8Backend(Backend):
             (("effective_bits", eff), ("depth", int(lp.depth))),
         )
 
+    @jax.named_scope("dot.int8")
     def dot(self, ctx, x, w, *, name: str = ""):
         if isinstance(w, PreparedWeight):
             # depth already baked into the stored grid — activation side only

@@ -62,25 +62,32 @@ class KernelBackend(Backend):
         from repro.kernels.cordic_fused import fused_dot_af, fused_dot_af_ref
 
         lp_af = ctx.layer_precision("af")
-        fn = fused_dot_af if _use_fused(ctx, x.shape[-1]) else fused_dot_af_ref
+        fused = _use_fused(ctx, x.shape[-1])
+        fn = fused_dot_af if fused else fused_dot_af_ref
         # Barriers at both ends: the kernel is a fusion boundary, so the XLA
         # chain must be one too. Fused into its neighbours it would change
         # their code — a skipped bf16 round (XLA's excess precision), another
         # accumulation order in the producing einsum — and the two paths
         # would no longer give the same bits.
-        out = fn(
-            jax.lax.optimization_barrier(x), w.data, w.point,
-            af_mode=af_mode,
-            af_depth=int(lp_af.depth),
-            af_fmt=lp_af.fmt,
-            compute_round=ctx.compute_dtype != jnp.float32,
-        )
-        return jax.lax.optimization_barrier(out.astype(ctx.compute_dtype))
+        with jax.named_scope("dot.kernel" if fused else "dot.kernel.xla_chain"):
+            out = fn(
+                jax.lax.optimization_barrier(x), w.data, w.point,
+                af_mode=af_mode,
+                af_depth=int(lp_af.depth),
+                af_fmt=lp_af.fmt,
+                compute_round=ctx.compute_dtype != jnp.float32,
+            )
+            return jax.lax.optimization_barrier(out.astype(ctx.compute_dtype))
 
     def dot(self, ctx, x, w, *, name: str = ""):
         if isinstance(w, PreparedWeight) and w.point is not None:
             return self._fused(ctx, x, w, "identity", name)
+        with jax.named_scope("dot.kernel"):
+            return self._mac(ctx, x, w, name)
 
+    def _mac(self, ctx, x, w, name: str):
+        """The standalone ``cordic_mac`` kernel: raw float weights, or the
+        legacy prepared layout."""
         from repro.kernels.cordic_mac import ops as mac_ops
 
         x2 = x.reshape(-1, x.shape[-1])

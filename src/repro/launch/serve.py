@@ -22,7 +22,13 @@ greedy token streams are bit-identical to single-device serving across mesh
 shapes. ``--metrics``/``--metrics-out`` report per-request SLO latency
 (TTFT, inter-token, queue-wait percentiles); ``--trace-out`` /
 ``--chrome-trace`` export the structured serve trace (JSONL replay format /
-Perfetto); ``--profile DIR`` additionally captures a ``jax.profiler`` trace.
+Perfetto); ``--profile DIR`` additionally captures a ``jax.profiler`` trace,
+in which the host thread shows the program's own spans by name
+(``frontend.tick``, ``engine.burst``, ``engine.burst.wait``, ...: the list is
+``repro.obs.trace.PROGRAM_SPANS``) and every device operation carries its
+named scope (``layers``, ``layer``, ``attention.*``, ``dot.<mode>``,
+``lm_head``, ``sample``) in its ``op_name``. The serve trace's timestamps
+are on the profiler's clock, so the two line up.
 
 Fault tolerance (``repro.resilience``, see ``docs/robustness.md``) — any of
 the flags below switches the server from fail-stop to shed/quarantine/
@@ -437,8 +443,11 @@ def main(argv=None):
                           help="write a Chrome-trace JSON (load in Perfetto "
                                "or chrome://tracing)")
     obs_args.add_argument("--profile", default=None, metavar="DIR",
-                          help="wrap the run in a jax.profiler trace "
-                               "(XLA-level; complements the serve trace)")
+                          help="wrap the run in a jax.profiler trace: the "
+                               "program's host spans (frontend.*, engine.*) "
+                               "and device scopes (layers, layer, attention.*, "
+                               "dot.<mode>, lm_head, sample) by name, on the "
+                               "serve trace's clock")
     fe_args = ap.add_argument_group(
         "streaming frontend",
         "continuous-batching scheduler (repro.serve.frontend): requests "

@@ -198,25 +198,32 @@ def cache_row_write(c, x, i):
       program (hlo_verifier RET_CHECK on the scatter index broadcast,
       jax 0.4.37) — the gather form is partitioner-friendly on every family.
       Start indices are clamped exactly like DUS clamps them.
+
+    Named scope ``attention.kv_write``.
     """
     from repro.sharding.partition import current_mesh_axes
 
     s = x.shape[1]
-    if not current_mesh_axes():
-        start = (lambda b_i: (b_i,) + (0,) * (x.ndim - 2))
-        upd = jax.vmap(lambda cb, xb, ib: jax.lax.dynamic_update_slice(cb, xb, start(ib)))
-        return upd(c, x.astype(c.dtype), i)
-    i = jnp.clip(i, 0, c.shape[1] - s)  # DUS start-clamping semantics
-    j = jnp.arange(c.shape[1], dtype=jnp.int32)[None, :] - i[:, None]  # (B, Smax)
-    valid = (j >= 0) & (j < s)
-    idx = jnp.clip(j, 0, s - 1).reshape(j.shape + (1,) * (x.ndim - 2))
-    gathered = jnp.take_along_axis(x.astype(c.dtype), idx, axis=1)
-    return jnp.where(valid.reshape(idx.shape), gathered, c)
+    with jax.named_scope("attention.kv_write"):
+        if not current_mesh_axes():
+            start = (lambda b_i: (b_i,) + (0,) * (x.ndim - 2))
+            upd = jax.vmap(lambda cb, xb, ib: jax.lax.dynamic_update_slice(cb, xb, start(ib)))
+            return upd(c, x.astype(c.dtype), i)
+        i = jnp.clip(i, 0, c.shape[1] - s)  # DUS start-clamping semantics
+        j = jnp.arange(c.shape[1], dtype=jnp.int32)[None, :] - i[:, None]  # (B, Smax)
+        valid = (j >= 0) & (j < s)
+        idx = jnp.clip(j, 0, s - 1).reshape(j.shape + (1,) * (x.ndim - 2))
+        gathered = jnp.take_along_axis(x.astype(c.dtype), idx, axis=1)
+        return jnp.where(valid.reshape(idx.shape), gathered, c)
 
 
 def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, cache=None,
               causal: bool = True):
-    """Returns (out, new_cache). cache = dict(k, v, index) for decode."""
+    """Returns (out, new_cache). cache = dict(k, v, index) for decode.
+
+    Named scopes: ``attention.kv_write`` (:func:`cache_row_write`) and
+    ``attention.core``, from the scores to the weighted sum; the projections
+    are dots (``dot.<backend>``)."""
     b, s, _ = x.shape
     kvh, g, hd = cfg.num_kv_heads, cfg.kv_groups, cfg.head_dim
 
@@ -242,10 +249,11 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
         kr = constrain(kr, "batch", None, "model", None)
         vr = constrain(vr, "batch", None, "model", None)
         k_pos = positions
-        if ctx.attn_impl == "flash":
-            out = _sdpa_flash_xla(q, kr, vr, positions, k_pos, causal=causal)
-        else:
-            out = _sdpa_chunked(q, kr, vr, positions, k_pos, causal=causal)
+        with jax.named_scope("attention.core"):
+            if ctx.attn_impl == "flash":
+                out = _sdpa_flash_xla(q, kr, vr, positions, k_pos, causal=causal)
+            else:
+                out = _sdpa_chunked(q, kr, vr, positions, k_pos, causal=causal)
         new_cache = None
     else:
         idx = cache["index"]  # (B,) int32: per-row next write slot
@@ -255,26 +263,27 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
         scale = 1.0 / math.sqrt(hd)
         from repro.sharding.partition import current_mesh_axes
 
-        if ctx.attn_impl == "decode_kernel" and not current_mesh_axes():
-            # Pallas cache-decode kernel: GQA resolved by index maps (no
-            # repeated-KV materialization), (S, Smax) score tile stays in
-            # VMEM. Mesh-sharded caches keep the XLA chain below.
-            from repro.kernels.decode_attention import gqa_decode_attention
+        with jax.named_scope("attention.core"):
+            if ctx.attn_impl == "decode_kernel" and not current_mesh_axes():
+                # Pallas cache-decode kernel: GQA resolved by index maps (no
+                # repeated-KV materialization), (S, Smax) score tile stays in
+                # VMEM. Mesh-sharded caches keep the XLA chain below.
+                from repro.kernels.decode_attention import gqa_decode_attention
 
-            out = gqa_decode_attention(q, ck, cv, positions, scale=scale)
-        else:
-            k_pos = jnp.arange(s_max)
-            # per-query causal validity: query at position p sees keys <= p.
-            # With s == 1 this is the classic decode mask; with s > 1 (batched
-            # prefill writing a whole prompt at once) it is causal within the
-            # new block.
-            valid = k_pos[None, None, :] <= positions[:, :, None]  # (B, Sq, Smax)
-            ckr = jnp.repeat(ck, g, axis=2) if g > 1 else ck
-            cvr = jnp.repeat(cv, g, axis=2) if g > 1 else cv
-            scores = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32), ckr.astype(jnp.float32))
-            scores = jnp.where(valid[:, None], scores * scale, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            out = jnp.einsum("bhqs,bshd->bqhd", probs.astype(cvr.dtype), cvr)
+                out = gqa_decode_attention(q, ck, cv, positions, scale=scale)
+            else:
+                k_pos = jnp.arange(s_max)
+                # per-query causal validity: query at position p sees keys <= p.
+                # With s == 1 this is the classic decode mask; with s > 1 (batched
+                # prefill writing a whole prompt at once) it is causal within the
+                # new block.
+                valid = k_pos[None, None, :] <= positions[:, :, None]  # (B, Sq, Smax)
+                ckr = jnp.repeat(ck, g, axis=2) if g > 1 else ck
+                cvr = jnp.repeat(cv, g, axis=2) if g > 1 else cv
+                scores = jnp.einsum("bqhd,bshd->bhqs", q.astype(jnp.float32), ckr.astype(jnp.float32))
+                scores = jnp.where(valid[:, None], scores * scale, -1e30)
+                probs = jax.nn.softmax(scores, axis=-1)
+                out = jnp.einsum("bhqs,bshd->bqhd", probs.astype(cvr.dtype), cvr)
         new_cache = {"k": ck, "v": cv, "index": idx + s}
 
     out = out.reshape(b, s, cfg.num_heads * hd)

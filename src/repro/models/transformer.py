@@ -170,16 +170,23 @@ def _mamba_layer(p, h, cfg, ctx, state, name="layer"):
 
 
 def _scan_segment(layer_fn, stacked_params, h, caches, *, remat: bool):
+    """One segment's layers as a ``lax.scan``. Named scopes: ``layers`` holds
+    the scan, ``layer`` its body; operations in ``layers`` but in no
+    ``layer`` move each layer's slice of the stacked weights and caches in
+    and out."""
     body = layer_fn
     if remat:
         body = jax.checkpoint(layer_fn, prevent_cse=False)
 
     def scan_fn(h, xs):
         p, cache = xs
-        h, new_cache, aux = body(p, h, cache)
+        with jax.named_scope("layer"):
+            h, new_cache, aux = body(p, h, cache)
         return h, (new_cache, aux)
 
-    h, (new_caches, auxs) = jax.lax.scan(scan_fn, h, (stacked_params, caches))
+    with jax.named_scope("layers"):
+        h, (new_caches, auxs) = jax.lax.scan(scan_fn, h,
+                                             (stacked_params, caches))
     return h, new_caches, auxs
 
 
@@ -306,12 +313,14 @@ def forward(params, batch, cfg: ModelConfig, ctx: EngineContext, *, remat: bool 
     positions = jnp.arange(s)
     h, _, aux = _run_segments(params, h, cfg, ctx, positions, None, remat=remat)
     h = constrain(h, "batch", None, None)
-    h = blocks.apply_norm(params["final_norm"], h, cfg)
     logits = constrain(_lm_head(params, h, cfg, ctx), "batch", None, "model")
     return logits, aux
 
 
+@jax.named_scope("lm_head")
 def _lm_head(params, h, cfg, ctx):
+    """The final norm and the output head (named scope ``lm_head``)."""
+    h = blocks.apply_norm(params["final_norm"], h, cfg)
     # prepared trees carry an explicit lm_head even when embeddings are tied
     # (prepare_params materializes the transposed bank once), so decoding
     # never re-quantizes the output head
@@ -334,7 +343,6 @@ def decode_step(params, tokens, cache, cfg: ModelConfig, ctx: EngineContext):
     index = _cache_index(cache)  # (B,) per-row decode positions
     positions = index[:, None] + jnp.arange(tokens.shape[1])[None, :]  # (B, S)
     h, new_caches, _ = _run_segments(params, h, cfg, ctx, positions, cache, remat=False)
-    h = blocks.apply_norm(params["final_norm"], h, cfg)
     logits = _lm_head(params, h, cfg, ctx)
     return logits, new_caches
 

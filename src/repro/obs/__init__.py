@@ -18,6 +18,12 @@ are bit-identical with observability on or off, asserted in
 * :mod:`repro.obs.observer` — :class:`ServingObserver`, the hook bundle
   ``BatchedServer(observer=...)`` drives at its existing host sync points.
 
+The serving program also opens its own profiler spans
+(:func:`repro.obs.trace.span`, listed in ``PROGRAM_SPANS``) and names the
+parts of its device programs with ``jax.named_scope``, which is metadata
+only; :func:`repro.obs.trace.compile_counter` attributes each backend
+compile to the innermost open span.
+
 Overhead is gated in CI: ``bench_serving --smoke`` fails if serving with an
 observer attached falls below 95% of uninstrumented tok/s.
 """

@@ -32,7 +32,13 @@ spec_rounds, decode_steps, host_transfers, controller_switches, compiles,
 evicted, cancelled, admission_ticks) and
 run-level gauges (``run_wall_s``, ``tok_s``, ``acceptance_rate`` under
 speculation). ``observer.trace`` (optional) records the structured event
-timeline documented in :mod:`repro.obs.trace`.
+timeline documented in :mod:`repro.obs.trace`, on the profiler's clock.
+
+``compiles`` counts the backend compiles JAX reports while the run is open
+(:class:`~repro.obs.trace.CompileCounter`), ``compiles.<span>`` those under
+each innermost program span (``compiles.frontend.prefill``: a new chunk
+bucket), and ``compile_cache_loads`` the ones the persistent compile cache
+answered.
 
 An observer is single-run: ``run_begin`` resets everything, and the server's
 :meth:`~repro.serve.engine.BatchedServer.snapshot` is the symmetric export.
@@ -44,7 +50,7 @@ import time
 from typing import Dict, List, Optional
 
 from .metrics import MetricsRegistry
-from .trace import TraceRecorder
+from .trace import TraceRecorder, compile_counter, profiler_clock
 
 __all__ = ["ServingObserver"]
 
@@ -66,7 +72,7 @@ class ServingObserver:
     """Metrics + trace hooks for one serving run (see module docstring)."""
 
     def __init__(self, metrics: bool = True, trace: bool = True,
-                 clock=time.perf_counter,
+                 clock=profiler_clock,
                  trace_sink: Optional[str] = None) -> None:
         self._clock = clock
         self._want_trace = trace
@@ -96,18 +102,21 @@ class ServingObserver:
         if self.trace is not None:
             self.trace.attach("run", meta)
             self.trace.begin("run", track="run", **meta)
+        compile_counter().listen(self._on_compile)
         for req in requests:
             self.request_submitted(req.rid, len(req.prompt), req.max_new)
 
     def request_submitted(self, rid: int, prompt_len: int, max_new: int,
-                          wall_ts: Optional[float] = None) -> None:
+                          submitted_perf: Optional[float] = None) -> None:
         """Register one arrival. ``run_begin`` calls this for the whole batch
         (the ``run()`` contract: the list arrives at entry); the streaming
         frontend calls it per submission at scheduler intake, passing
-        ``wall_ts`` — the raw clock reading stamped on the submitting thread
-        — so queue-wait and TTFT anchor at the true submit time, not at the
-        tick that first saw the request."""
-        now = self._at(wall_ts)
+        ``submitted_perf`` — the ``time.perf_counter()`` reading stamped on
+        the submitting thread — so queue-wait and TTFT anchor at the true
+        submit time, not at the tick that first saw the request."""
+        now = self._now()
+        if submitted_perf is not None:
+            now -= time.perf_counter() - submitted_perf
         self.requests[rid] = _ReqState(
             submit=now, prompt_len=prompt_len, max_new=max_new)
         self._count("requests")
@@ -123,6 +132,7 @@ class ServingObserver:
         aborted run still exports a coherent record."""
         now = self._now()
         self.aborted = aborted
+        compile_counter().unlisten(self._on_compile)
         for rid, st in self.requests.items():
             if st.done is None and st.admit is not None:
                 self._count("evicted")
@@ -292,12 +302,16 @@ class ServingObserver:
                 self.trace.end(f"request:{rid}", track=_slot_track(st),
                                rid=rid, tokens=tokens)
 
-    def compile_event(self, what: str, **args) -> None:
-        """A new XLA program is about to be built (first visit to a prefill
-        bucket / burst variant) — the next span's wall time includes it."""
+    def _on_compile(self, where: str, seconds: float, cached: bool) -> None:
+        """One backend compile (or persistent-cache load) under the program
+        span ``where``, reported by the process's compile counter."""
         self._count("compiles")
+        self._count(f"compiles.{where}")
+        if cached:
+            self._count("compile_cache_loads")
         if self.trace is not None:
-            self.trace.instant("compile", track="engine", what=what, **args)
+            self.trace.instant("compile", track="engine", what=where,
+                               seconds=seconds, cached=cached)
 
     # -- decode bursts / speculative rounds -----------------------------------
 
@@ -407,13 +421,6 @@ class ServingObserver:
     def _now(self) -> float:
         return self.trace.now() if self.trace is not None else (
             self._clock())
-
-    def _at(self, wall_ts: Optional[float]) -> float:
-        """Map a raw clock reading onto the observer's time base (trace time
-        when a trace is attached); ``None`` means "now"."""
-        if wall_ts is None:
-            return self._now()
-        return self.trace.at(wall_ts) if self.trace is not None else wall_ts
 
     def _observe(self, name: str, v: float, n: int = 1) -> None:
         if self.metrics is not None:

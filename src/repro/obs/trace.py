@@ -1,4 +1,5 @@
-"""Structured serving traces: versioned JSONL + Chrome-trace/Perfetto export.
+"""Structured serving traces: versioned JSONL + Chrome-trace/Perfetto export,
+program spans on the profiler's clock, and the compile counter.
 
 A :class:`TraceRecorder` accumulates timestamped events during one serving
 run. Events are recorded host-side at the engine's existing synchronization
@@ -8,11 +9,18 @@ dispatch plus any device wait the call already contained. (The draft/verify
 spans inside a speculative round are dispatch-only: jax dispatch is async and
 the round synchronizes once, at its single host transfer.)
 
+Timestamps come from :func:`profiler_clock`, the clock the JAX profiler
+stamps its host events with (``CLOCK_REALTIME``). ``jax.profiler.ProfileData``
+reports an event's ``start_ns`` from the profile's ``profile_start_time``
+(a stat of its ``Task Environment`` plane), so such an event lands at
+``(profile_start_time + start_ns - t0_ns) / 1e9`` in trace time, where
+``t0_ns`` is the header's anchor.
+
 Two exports from the same event list:
 
 * **JSONL** (:meth:`TraceRecorder.write_jsonl` / :func:`read_trace`): the
   replayable serving-telemetry format. Line 1 is the header
-  (``schema``/``version``, wall-clock anchor, run metadata, optional sharding
+  (``schema``/``version``, clock anchor, run metadata, optional sharding
   report and collective-bytes snapshot); every following line is one event
   ``{"ts": seconds-since-run-start, "ph": "B"|"E"|"I", "name": ...,
   "track": ..., "args": {...}}``. This is the trace the ROADMAP's
@@ -26,19 +34,175 @@ Two exports from the same event list:
 B/E spans must nest per track; :meth:`end` enforces it at record time so an
 exported trace is always well-formed, and :meth:`close_open` settles any
 spans left open by an aborted run.
+
+**Program spans** (:func:`span`) mark the layer boundaries of the serving
+program itself: every name is listed once in :data:`PROGRAM_SPANS`. Each is
+a ``jax.profiler.TraceAnnotation``, so a profiler trace of a serving run
+shows them on the host thread by name; without a running profiler a span
+costs one annotation object and a push and pop of the thread's span stack.
+:class:`CompileCounter` attributes every backend compile to the innermost
+span open on the compiling thread.
 """
 from __future__ import annotations
 
 import json
 import os
+import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["TRACE_SCHEMA", "TRACE_VERSION", "TraceRecorder", "iter_trace",
-           "read_trace"]
+import jax
+
+__all__ = ["PROGRAM_SPANS", "TRACE_SCHEMA", "TRACE_VERSION", "CompileCounter",
+           "TraceRecorder", "compile_counter", "iter_trace", "open_spans",
+           "profiler_clock", "read_trace", "span"]
 
 TRACE_SCHEMA = "carmen-serve-trace"
 TRACE_VERSION = 1
+
+# Every span the serving program opens, with its meaning. Nesting:
+# frontend.tick holds frontend.intake, frontend.prefill (engine.chunk,
+# engine.admit, engine.admit.wait; engine.prefill and engine.prefill.wait
+# for a monolithic prefill), then engine.burst, engine.burst.wait and
+# engine.settle (or engine.spec.*, then engine.settle), then frontend.flush.
+# ``run()`` opens the engine.* spans alone.
+PROGRAM_SPANS: Tuple[Tuple[str, str], ...] = (
+    ("frontend.tick", "one ContinuousScheduler.step, intake to flush"),
+    ("frontend.intake", "the inbox, cancellations and the resilience sweeps"),
+    ("frontend.prefill", "the tick's prefill budget: chunks, admits, prefills"),
+    ("frontend.flush", "committed tokens pushed to their stream handles"),
+    ("engine.chunk", "dispatch of one chunked-prefill program"),
+    ("engine.admit", "dispatch of the admit program that ends a chunked prefill"),
+    ("engine.admit.wait", "the admit's first token and margin reaching the host"),
+    ("engine.prefill", "dispatch of one whole-prompt prefill program"),
+    ("engine.prefill.wait", "the prefill's first token reaching the host"),
+    ("engine.burst", "dispatch of one decode burst"),
+    ("engine.burst.wait", "the burst's tokens, margins and faults reaching the host"),
+    ("engine.spec.draft", "dispatch of a speculative round's draft loop"),
+    ("engine.spec.verify", "dispatch of a speculative round's verify step"),
+    ("engine.spec.wait", "the round's emitted tokens and flags reaching the host"),
+    ("engine.settle", "host commit of a round's tokens, then its retirements"),
+)
+SPAN_NAMES = frozenset(name for name, _ in PROGRAM_SPANS)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+NO_SPAN = "(no span)"
+
+_local = threading.local()
+
+
+def profiler_clock() -> float:
+    """Seconds on the clock the JAX profiler stamps host events with."""
+    return time.time_ns() * 1e-9
+
+
+def _stack() -> List[str]:
+    stack = getattr(_local, "spans", None)
+    if stack is None:
+        stack = _local.spans = []
+    return stack
+
+
+def open_spans() -> Tuple[str, ...]:
+    """The program spans open on this thread, outermost first."""
+    return tuple(_stack())
+
+
+class span:
+    """``with span("engine.burst"): ...`` — one program span (a name of
+    :data:`PROGRAM_SPANS`; ``args`` ride on the profiler event, e.g.
+    ``rid=``). The thread's span stack is popped however the body exits."""
+
+    __slots__ = ("name", "_annotation")
+
+    def __init__(self, name: str, **args) -> None:
+        if name not in SPAN_NAMES:
+            raise ValueError(f"{name!r} is not a program span; add it to "
+                             "PROGRAM_SPANS")
+        self.name = name
+        self._annotation = jax.profiler.TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "span":
+        _stack().append(self.name)
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        try:
+            self._annotation.__exit__(*exc)
+        finally:
+            _stack().pop()
+
+
+class CompileCounter:
+    """Backend compiles in this process, read from JAX's monitoring events.
+
+    Each compile is attributed to the innermost program span open on the
+    thread that compiled it (:data:`NO_SPAN` outside any). ``by_span`` maps
+    span -> ``[compiles, seconds, cache_loads]``: a load from the persistent
+    compile cache still fires the compile event (short), and is counted as a
+    compile and, besides, as a load. Listeners (bound methods, held weakly)
+    are called as ``listener(span, seconds, cached)`` per compile.
+    """
+
+    def __init__(self) -> None:
+        self.by_span: Dict[str, List] = {}
+        self._listeners: List[weakref.WeakMethod] = []
+        self._hit = threading.local()
+
+    @property
+    def count(self) -> int:
+        return sum(c for c, _, _ in self.by_span.values())
+
+    @property
+    def seconds(self) -> float:
+        return sum(s for _, s, _ in self.by_span.values())
+
+    def listen(self, method) -> None:
+        self.unlisten(method)
+        self._listeners.append(weakref.WeakMethod(method))
+
+    def unlisten(self, method) -> None:
+        self._listeners = [m for m in self._listeners
+                           if m() is not None and m() != method]
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == CACHE_HIT_EVENT:
+            self._hit.flag = True
+
+    def _on_duration(self, event: str, duration: float, **_) -> None:
+        if event != COMPILE_EVENT:
+            return
+        cached = getattr(self._hit, "flag", False)
+        self._hit.flag = False
+        stack = _stack()
+        where = stack[-1] if stack else NO_SPAN
+        entry = self.by_span.setdefault(where, [0, 0.0, 0])
+        entry[0] += 1
+        entry[1] += duration
+        entry[2] += int(cached)
+        for ref in list(self._listeners):
+            method = ref()
+            if method is not None:
+                method(where, duration, cached)
+
+
+_COUNTER: Optional[CompileCounter] = None
+_COUNTER_LOCK = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process's :class:`CompileCounter` (its listeners are registered
+    with ``jax.monitoring`` on the first call)."""
+    global _COUNTER
+    with _COUNTER_LOCK:
+        if _COUNTER is None:
+            _COUNTER = CompileCounter()
+            jax.monitoring.register_event_listener(_COUNTER._on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _COUNTER._on_duration)
+        return _COUNTER
 
 
 class TraceRecorder:
@@ -53,15 +217,19 @@ class TraceRecorder:
     flushes too — ``flush()`` is idempotent and explicit calls remain fine.
     """
 
-    def __init__(self, clock=time.perf_counter,
+    def __init__(self, clock=profiler_clock,
                  sink: Optional[str] = None) -> None:
         self._clock = clock
+        # the anchor of trace time on the profiler's clock (module
+        # docstring); with another clock it only dates the run
+        t0_ns = time.time_ns()
         self._t0 = clock()
         self.sink = sink
         self.header: Dict = {
             "schema": TRACE_SCHEMA,
             "version": TRACE_VERSION,
-            "t0_unix": time.time(),
+            "t0_unix": t0_ns * 1e-9,
+            "t0_ns": t0_ns,
             "meta": {},
         }
         self.events: List[Dict] = []
@@ -70,12 +238,6 @@ class TraceRecorder:
     def now(self) -> float:
         """Seconds since recorder creation (the trace time base)."""
         return self._clock() - self._t0
-
-    def at(self, clock_value: float) -> float:
-        """Convert a raw reading of the recorder's clock into trace time —
-        how streaming submit timestamps (stamped on the caller's thread)
-        land on the same time base as every other event."""
-        return clock_value - self._t0
 
     def attach(self, key: str, value) -> None:
         """Attach a header field (sharding report, collective bytes, ...)."""

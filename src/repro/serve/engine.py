@@ -88,6 +88,7 @@ import numpy as np
 
 from repro.core import EngineContext, prepare_params
 from repro.models import ModelApi
+from repro.obs.trace import span
 from repro.sharding import partition
 
 from .kvcache import bucket_length, scatter_rows, with_cache_positions
@@ -220,6 +221,12 @@ def make_decode_burst(model: ModelApi, ctx: EngineContext, burst: int,
     categorical per step (a real cost on small models), bit-identical to the
     sampled variant at ``temp <= 0``. The server picks per burst from the
     active requests' temperatures.
+
+    Named scopes in the compiled program: ``burst`` holds the scan (alone,
+    the loop's copies of its carried state and the embedding lookup), and
+    in it the model's (``layers``, ``layer``, ``attention.*``,
+    ``dot.<backend>``, ``lm_head``) and ``sample``: each step's fault
+    probe, token choice and margin.
     """
 
     def decode_burst(tree, cache, state):
@@ -228,30 +235,31 @@ def make_decode_burst(model: ModelApi, ctx: EngineContext, burst: int,
         def step(carry, _):
             tok, cache, count, rem, fault = carry
             logits, cache = model.decode_step(tree, tok, cache, ctx)
-            last = logits[:, -1, :].astype(jnp.float32)
-            bad = ~jnp.all(jnp.isfinite(last), axis=-1)
-            if logit_limit is not None:
-                bad |= jnp.any(jnp.abs(last) > logit_limit, axis=-1)
-            fault = fault | bad
-            if sampled:
-                nxt = _sample_slots(last, keys, count, temps)
-                margin = top2_margin(last)
-            else:
-                # one top_k yields the greedy token AND the margin (top_k and
-                # argmax share first-occurrence tie-breaking)
-                top2, idx = jax.lax.top_k(last, 2)
-                nxt = idx[:, :1].astype(jnp.int32)
-                margin = top2[..., 0] - top2[..., 1]
+            with jax.named_scope("sample"):
+                last = logits[:, -1, :].astype(jnp.float32)
+                bad = ~jnp.all(jnp.isfinite(last), axis=-1)
+                if logit_limit is not None:
+                    bad |= jnp.any(jnp.abs(last) > logit_limit, axis=-1)
+                fault = fault | bad
+                if sampled:
+                    nxt = _sample_slots(last, keys, count, temps)
+                    margin = top2_margin(last)
+                else:
+                    # one top_k yields the greedy token AND the margin (top_k
+                    # and argmax share first-occurrence tie-breaking)
+                    top2, idx = jax.lax.top_k(last, 2)
+                    nxt = idx[:, :1].astype(jnp.int32)
+                    margin = top2[..., 0] - top2[..., 1]
             active = (rem > 0).astype(jnp.int32)
             return (nxt, cache, count + active, rem - active, fault), (
                 nxt[:, 0], margin, fault,
             )
 
-        (tok, cache, count, rem, fault), (toks, margins, faults) = jax.lax.scan(
-            step, (state["tok"], cache, state["count"], state["rem"],
-                   state["fault"]),
-            None, length=burst,
-        )
+        with jax.named_scope("burst"):
+            (tok, cache, count, rem, fault), (toks, margins, faults) = (
+                jax.lax.scan(step, (state["tok"], cache, state["count"],
+                                    state["rem"], state["fault"]),
+                             None, length=burst))
         state = dict(state, tok=tok, count=count, rem=rem, fault=fault)
         return (cache, state, jnp.moveaxis(toks, 0, 1),
                 jnp.moveaxis(margins, 0, 1), jnp.moveaxis(faults, 0, 1))
@@ -535,7 +543,6 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
         self._slot_start = np.zeros((self.slots,), np.int32)  # committed KV rows
         self.host_transfers = 0
         self._run_complete: Optional[bool] = None  # None: never ran
-        self._seen_buckets = set()  # prefill shapes already compiled
         # resilience accounting (per run, reset in _begin_run)
         self.outcomes: Dict[int, object] = {}  # rid -> RequestOutcome
         self._round_idx = 0
@@ -626,20 +633,18 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
         bucket = bucket_length(len(prompt), self.max_len)
         obs, point_name = self.observer, self._serving_point()
         if obs is not None:
-            if bucket not in self._seen_buckets:
-                obs.compile_event("prefill", bucket=bucket)
             obs.prefill_begin(req.rid, bucket, point_name)
-        self._seen_buckets.add(bucket)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
-        with self._scope():
+        with span("engine.prefill", rid=req.rid), self._scope():
             tok, margin, self.cache, self._state = self.prefill(
                 tree, self.cache, self._state, jnp.asarray(padded),
                 jnp.int32(len(prompt)), jnp.int32(slot),
                 jax.random.PRNGKey(seed), jnp.float32(req.temperature),
                 jnp.int32(req.max_new),
             )
-        tok, margin = jax.device_get((tok, margin))
+        with span("engine.prefill.wait", rid=req.rid):
+            tok, margin = jax.device_get((tok, margin))
         self.host_transfers += 1
         self._slot_start[slot] = len(prompt)
         req.generated = [int(tok[0, 0])]
@@ -721,11 +726,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
                 if not self.active:
                     continue
                 queue_depth, free_slots = len(queue), len(free)
-                if self.spec is not None:
-                    summary = self._spec_round(slot_of)
-                else:
-                    summary = self._burst_round(slot_of)
-                misses = self._settle_round(summary, results, slot_of, free)
+                summary, misses = self._decode_round(slot_of, results, free)
                 if self.controller is not None:
                     self._observe(summary["point"], summary["emitted"],
                                   summary["steps"], queue_depth, free_slots,
@@ -762,6 +763,15 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             return
         self.active[req.rid] = req
         slot_of[req.rid] = slot
+
+    def _decode_round(self, slot_of: Dict[int, int], results: Dict,
+                      free: List[int]) -> Tuple[Dict, int]:
+        """One decode burst, or one speculative round, over the active slots,
+        then its settle (:meth:`_settle_round`). Returns the round summary
+        and the deadline misses."""
+        if self.spec is not None:
+            return self._spec_round(slot_of, results, free)
+        return self._burst_round(slot_of, results, free)
 
     def _settle_round(self, summary: Dict, results: Dict,
                       slot_of: Dict[int, int], free: List[int]) -> int:
@@ -1163,14 +1173,17 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             )
         return self._burst_fns[sampled]
 
-    def _burst_round(self, slot_of) -> Dict:
+    def _burst_round(self, slot_of, results: Dict,
+                     free: List[int]) -> Tuple[Dict, int]:
         """One decode burst over the active slots: ``burst`` scan steps on
-        device, one host transfer, per-slot budget clipping on the host.
+        device, one host transfer, per-slot budget clipping on the host,
+        then the round's settle.
 
-        Returns the round summary the scheduler acts on: tokens emitted,
+        Returns the round summary the scheduler acts on (tokens emitted,
         the executed point, the min margin over *clean* committed tokens,
-        and the rids whose lanes faulted (their commit is clipped to the
-        steps before the first bad logit; the scheduler quarantines them).
+        and the rids whose lanes faulted: their commit is clipped to the
+        steps before the first bad logit, and the settle quarantines them)
+        and the settle's deadline misses.
         """
         obs = self.observer
         if self.injector is not None:
@@ -1179,16 +1192,25 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
         point = self.controller.point if self.controller is not None else None
         sampled = any(r.temperature > 0.0 for r in self.active.values())
         if obs is not None:
-            if sampled not in self._burst_fns:
-                obs.compile_event("burst", sampled=sampled)
             obs.burst_begin(point)
-        with self._scope():
+        with span("engine.burst"), self._scope():
             self.cache, self._state, toks, margins, faults = (
                 self.decode_burst(sampled)(
                     self._serving_tree(), self.cache, self._state,
                 ))
-        toks, margins, faults = jax.device_get((toks, margins, faults))
+        with span("engine.burst.wait"):
+            toks, margins, faults = jax.device_get((toks, margins, faults))
         self.host_transfers += 1
+        with span("engine.settle"):
+            summary = self._commit_burst(point, toks, margins, faults,
+                                         slot_of)
+            return summary, self._settle_round(summary, results, slot_of,
+                                               free)
+
+    def _commit_burst(self, point, toks, margins, faults, slot_of) -> Dict:
+        """The host side of one burst: each active request's emitted run,
+        clipped to its budget and to the steps before a fault."""
+        obs = self.observer
         isolate = (self.resilience is not None
                    and self.resilience.fault_isolation)
         emitted = 0
@@ -1221,8 +1243,11 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
             "fault_reason": "decode_nonfinite",
         }
 
-    def _spec_round(self, slot_of) -> Dict:
-        """One draft-k-then-verify round over the active slots.
+    def _spec_round(self, slot_of, results: Dict,
+                    free: List[int]) -> Tuple[Dict, int]:
+        """One draft-k-then-verify round over the active slots, then its
+        settle; returns the summary and the deadline misses, as
+        :meth:`_burst_round` does.
 
         Each active request gains between 1 (first draft rejected) and
         ``draft_len + 1`` (all accepted + bonus) tokens, clipped to its
@@ -1254,6 +1279,17 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
                 self._slot_start, draft_point=draft_point,
             )
         self.host_transfers += 1
+        with span("engine.settle"):
+            summary = self._commit_spec(st, point, emitted, accepted, margins,
+                                        draft_fault, verify_fault, slot_of)
+            return summary, self._settle_round(summary, results, slot_of,
+                                               free)
+
+    def _commit_spec(self, st, point, emitted, accepted, margins, draft_fault,
+                     verify_fault, slot_of) -> Dict:
+        """The host side of one speculative round: commit each lane's
+        accepted run and re-sync the device slot state."""
+        obs = self.observer
         isolate = (self.resilience is not None
                    and self.resilience.fault_isolation)
         accs, emits, round_margins = [], [], []

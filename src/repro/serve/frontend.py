@@ -62,6 +62,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 from .engine import BatchedServer, Request
 from .kvcache import bucket_length
 
@@ -190,7 +192,7 @@ class ContinuousScheduler:
         self.server = server
         self.config = config if config is not None else FrontendConfig()
         self._lock = threading.Lock()
-        self._inbox: List = []          # (request, handle, wall_ts, reason)
+        self._inbox: List = []          # (request, handle, perf stamp, reason)
         self._known: set = set()        # every rid ever submitted
         self.handles: Dict[int, StreamHandle] = {}
         self.queue: List[Request] = []
@@ -202,7 +204,6 @@ class ContinuousScheduler:
         self._closed = False
         self._shed_since = 0            # sheds since last controller observe
         self._rows_since_burst = 0      # prefill rows stalling active slots
-        self._chunk_buckets: set = set()
         self.stats = {
             "ticks": 0, "bursts": 0, "submitted": 0, "prefill_rows": 0,
             "max_prefill_rows_between_bursts": 0,
@@ -307,16 +308,23 @@ class ContinuousScheduler:
         Order: drain arrivals and cancellations, re-run the resilience
         sweeps over the queue, run at most ``chunk_tokens`` prefill rows,
         then one decode burst / speculative round, then stream the committed
-        tokens out to their handles.
+        tokens out to their handles. Each phase is a program span
+        (:data:`repro.obs.trace.PROGRAM_SPANS`) inside ``frontend.tick``.
         """
         if not self._open or self._closed:
             raise RuntimeError("scheduler is not open")
+        with span("frontend.tick"):
+            return self._tick()
+
+    def _tick(self) -> bool:
         server = self.server
-        did = self._drain_inbox()
-        did = self._apply_cancellations() or did
-        did = self._police_queue() or did
+        with span("frontend.intake"):
+            did = self._drain_inbox()
+            did = self._apply_cancellations() or did
+            did = self._police_queue() or did
         if not (self.queue or self.job is not None or server.active):
-            self._flush()
+            with span("frontend.flush"):
+                self._flush()
             return did
         obs = server.observer
         if obs is not None:
@@ -324,7 +332,8 @@ class ContinuousScheduler:
                                len(self.free))
         self.stats["ticks"] += 1
         active_before = bool(server.active)
-        rows = self._prefill_tick()
+        with span("frontend.prefill"):
+            rows = self._prefill_tick()
         self.stats["prefill_rows"] += rows
         if active_before:
             # only rows run while a slot was already decoding can stall its
@@ -332,11 +341,8 @@ class ContinuousScheduler:
             self._rows_since_burst += rows
         if server.active:
             queue_depth, free_slots = len(self.queue), len(self.free)
-            summary = (server._spec_round(self.slot_of)
-                       if server.spec is not None
-                       else server._burst_round(self.slot_of))
-            misses = server._settle_round(summary, self.results, self.slot_of,
-                                          self.free)
+            summary, misses = server._decode_round(self.slot_of, self.results,
+                                                   self.free)
             if server.controller is not None:
                 server._observe(summary["point"], summary["emitted"],
                                 summary["steps"], queue_depth, free_slots,
@@ -348,7 +354,8 @@ class ContinuousScheduler:
                 self.stats["max_prefill_rows_between_bursts"],
                 self._rows_since_burst)
             self._rows_since_burst = 0
-        self._flush()
+        with span("frontend.flush"):
+            self._flush()
         return True
 
     def drain(self) -> Dict[int, List[int]]:
@@ -384,7 +391,7 @@ class ContinuousScheduler:
             if server.observer is not None:
                 server.observer.request_submitted(
                     req.rid, len(np.asarray(req.prompt)), req.max_new,
-                    wall_ts=wall)
+                    submitted_perf=wall)
             if reason is not None:
                 server._shed(req, reason)
                 self._shed_since += 1
@@ -489,28 +496,29 @@ class ContinuousScheduler:
         bucket = bucket_length(n, server.max_len)
         chunk_fn, admit_fn = server.chunk_fns()
         final = job.done + n >= len(job.prompt)
+        rid = job.req.rid
         if obs is not None:
-            if bucket not in self._chunk_buckets:
-                obs.compile_event("prefill_chunk", bucket=bucket)
-            obs.prefill_chunk_begin(job.req.rid, job.done, n, bucket, point)
-        self._chunk_buckets.add(bucket)
+            obs.prefill_chunk_begin(rid, job.done, n, bucket, point)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = job.prompt[job.done:job.done + n]
-        job.row, job.last = chunk_fn(
-            server._serving_tree(), job.row, job.last, jnp.asarray(padded),
-            jnp.int32(job.done), jnp.int32(n))
+        with span("engine.chunk", rid=rid):
+            job.row, job.last = chunk_fn(
+                server._serving_tree(), job.row, job.last, jnp.asarray(padded),
+                jnp.int32(job.done), jnp.int32(n))
         job.done += n
         if not final:
             if obs is not None:
-                obs.prefill_chunk_end(job.req.rid, final=False)
+                obs.prefill_chunk_end(rid, final=False)
             return
         req, slot = job.req, job.slot
         seed = req.seed if req.seed is not None else req.rid
-        tok, margin, server.cache, server._state = admit_fn(
-            server.cache, server._state, job.row, job.last, jnp.int32(slot),
-            jax.random.PRNGKey(seed), jnp.float32(req.temperature),
-            jnp.int32(req.max_new))
-        tok, margin = jax.device_get((tok, margin))
+        with span("engine.admit", rid=rid):
+            tok, margin, server.cache, server._state = admit_fn(
+                server.cache, server._state, job.row, job.last,
+                jnp.int32(slot), jax.random.PRNGKey(seed),
+                jnp.float32(req.temperature), jnp.int32(req.max_new))
+        with span("engine.admit.wait", rid=rid):
+            tok, margin = jax.device_get((tok, margin))
         server.host_transfers += 1
         server._slot_start[slot] = len(job.prompt)
         req.generated = [int(tok[0, 0])]

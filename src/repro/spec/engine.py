@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import EngineContext
 from repro.models import ModelApi
+from repro.obs.trace import span
 from repro.runtime.bank import MultiPointBank
 from repro.serve.engine import exact_rounding
 
@@ -115,21 +116,26 @@ class SpeculativeDecoder:
         start = jnp.asarray(start, jnp.int32)
         if obs is not None:
             obs.spec_stage_begin("draft", point)
-        draft_toks, draft_probs, cache = self.draft_loop(
-            self.bank.tree(point), tokens, cache, base_keys, counts, temps,
-            round_idx,
-        )
+        with span("engine.spec.draft"):
+            draft_toks, draft_probs, cache = self.draft_loop(
+                self.bank.tree(point), tokens, cache, base_keys, counts, temps,
+                round_idx,
+            )
         if obs is not None:
             obs.spec_stage_end("draft", point)
             obs.spec_stage_begin("verify", self.verify_point)
-        emitted, accepted, margins, draft_fault, verify_fault, cache = self.verify(
-            self.bank.tree(self.verify_point), tokens, draft_toks, draft_probs,
-            cache, start, base_keys, counts, temps, round_idx,
-        )
+        with span("engine.spec.verify"):
+            (emitted, accepted, margins, draft_fault, verify_fault,
+             cache) = self.verify(
+                self.bank.tree(self.verify_point), tokens, draft_toks,
+                draft_probs, cache, start, base_keys, counts, temps, round_idx,
+            )
         if obs is not None:
             obs.spec_stage_end("verify", self.verify_point)
-        emitted, accepted, margins, draft_fault, verify_fault = jax.device_get(
-            (emitted, accepted, margins, draft_fault, verify_fault))
+        with span("engine.spec.wait"):
+            (emitted, accepted, margins, draft_fault,
+             verify_fault) = jax.device_get(
+                (emitted, accepted, margins, draft_fault, verify_fault))
         if obs is not None:
             obs.spec_commit(accepted)
         return emitted, accepted, margins, draft_fault, verify_fault, cache, point
