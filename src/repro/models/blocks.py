@@ -217,11 +217,37 @@ def cache_row_write(c, x, i):
         return jnp.where(valid.reshape(idx.shape), gathered, c)
 
 
+def cache_layer_write(c, x, i, layer):
+    """The carried form of :func:`cache_row_write`: write block ``x``
+    (B, S, ...) into rows [i, i+S) of layer ``layer`` of the stacked cache
+    ``c`` (L, B, Smax, ...), ``i`` (B,) int32, in place.
+
+    One ``dynamic_update_slice`` per slot, each clamping its start index as
+    the single-layer write does. A ``vmap`` over the slot axis would lower
+    to a scatter for which XLA lays the stack out slot-minor and copies the
+    whole cache at the program's entry and exit. Single device only.
+
+    Named scope ``attention.kv_write``.
+    """
+    x = x.astype(c.dtype)
+    tail = (0,) * (x.ndim - 2)
+    with jax.named_scope("attention.kv_write"):
+        for b in range(x.shape[0]):
+            c = jax.lax.dynamic_update_slice(c, x[b][None, None],
+                                             (layer, b, i[b]) + tail)
+    return c
+
+
 def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, cache=None,
               causal: bool = True):
-    """Returns (out, new_cache). cache = dict(k, v, index) for decode.
+    """Returns (out, new_cache). cache = dict(k, v, index) for decode: one
+    layer's cache, or, with a ``layer`` entry, the segment's whole stacked
+    cache carried through the layer scan in a decode step
+    (``transformer._scan_segment``): this layer's rows are written in place
+    and its K/V read from the stack.
 
-    Named scopes: ``attention.kv_write`` (:func:`cache_row_write`) and
+    Named scopes: ``attention.kv_write`` (:func:`cache_row_write`,
+    :func:`cache_layer_write`) and
     ``attention.core``, from the scores to the weighted sum; the projections
     are dots (``dot.<backend>``)."""
     b, s, _ = x.shape
@@ -256,9 +282,21 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
                 out = _sdpa_chunked(q, kr, vr, positions, k_pos, causal=causal)
         new_cache = None
     else:
-        idx = cache["index"]  # (B,) int32: per-row next write slot
-        ck = cache_row_write(cache["k"], k, idx)
-        cv = cache_row_write(cache["v"], v, idx)
+        if "layer" in cache:
+            layer = cache["layer"]
+            idx = jax.lax.dynamic_index_in_dim(cache["index"], layer, keepdims=False)
+            ck_all = cache_layer_write(cache["k"], k, idx, layer)
+            cv_all = cache_layer_write(cache["v"], v, idx, layer)
+            ck = jax.lax.dynamic_index_in_dim(ck_all, layer, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(cv_all, layer, keepdims=False)
+            new_cache = dict(cache, k=ck_all, v=cv_all,
+                             index=jax.lax.dynamic_update_index_in_dim(
+                                 cache["index"], idx + s, layer, 0))
+        else:
+            idx = cache["index"]  # (B,) int32: per-row next write slot
+            ck = cache_row_write(cache["k"], k, idx)
+            cv = cache_row_write(cache["v"], v, idx)
+            new_cache = {"k": ck, "v": cv, "index": idx + s}
         s_max = ck.shape[1]
         scale = 1.0 / math.sqrt(hd)
         from repro.sharding.partition import current_mesh_axes
@@ -284,7 +322,6 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
                 scores = jnp.where(valid[:, None], scores * scale, -1e30)
                 probs = jax.nn.softmax(scores, axis=-1)
                 out = jnp.einsum("bhqs,bshd->bqhd", probs.astype(cvr.dtype), cvr)
-        new_cache = {"k": ck, "v": cv, "index": idx + s}
 
     out = out.reshape(b, s, cfg.num_heads * hd)
     wo = p["wo"].reshape(cfg.num_heads * hd, cfg.d_model)

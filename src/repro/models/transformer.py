@@ -6,7 +6,11 @@ Design constraints that shaped this file:
   stacked parameters (stacked leading 'layers' axis). MoE models with a dense
   prefix (deepseek) or interleaving (llama4) scan each homogeneous segment.
 * **one code path for train / prefill / decode**: segments take an optional
-  cache pytree (stacked along layers, consumed as scan xs, emitted as ys).
+  cache pytree, stacked along layers. In a one-token decode step a stacked
+  attention KV cache on one device is the scan's carry, written one row in
+  place per layer; blocks of several tokens, recurrent state, MLA caches and
+  every cache under a mesh are consumed as scan xs and emitted as ys
+  (``_scan_segment``).
 * **CARMEN everywhere**: all projections go through ``EngineContext``; MLP
   activations go through the multi-AF block mapping.
 """
@@ -21,7 +25,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import EngineContext
 
-from repro.sharding.partition import constrain
+from repro.sharding.partition import constrain, current_mesh_axes
 
 from . import blocks, mamba2, mla
 from .params import ParamSpec, stack_layers
@@ -169,14 +173,76 @@ def _mamba_layer(p, h, cfg, ctx, state, name="layer"):
 # ---------------------------------------------------------------------------
 
 
+_ATTN_CACHE = frozenset(("k", "v", "index"))
+
+
+def _carried(caches, block: int) -> bool:
+    """Whether a segment's cache travels in the layer scan's carry: a stacked
+    attention KV cache (``blocks.init_attn_cache``), or a dict of them
+    (``pair``), on one device, written one token a row (``block`` 1, the
+    decode step). Recurrent state and MLA caches do not, nor does any cache
+    under a mesh, where ``blocks.cache_row_write``'s gather-and-select form
+    rewrites the whole layer anyway.
+
+    Nor does a block of several tokens (a prefill chunk, a speculative
+    verify): its scores and values are MXU dots, and on a TPU v5e a block
+    reading its K/V out of the carried stack computed other numbers than
+    one reading the layer's own slab (the compiled dots then take the cache
+    converted to bf16 in a fusion of its own), while single-token steps
+    matched bit for bit."""
+    if block != 1 or not isinstance(caches, dict) or current_mesh_axes():
+        return False
+    if set(caches) == _ATTN_CACHE:
+        return True
+    return all(isinstance(c, dict) and set(c) == _ATTN_CACHE
+               for c in caches.values())
+
+
+def _layer_view(caches, layer):
+    """The stacked cache as one layer sees it: each attention cache gains a
+    ``layer`` entry, its index in the stack (``blocks.attention``)."""
+    if set(caches) == _ATTN_CACHE:
+        return dict(caches, layer=layer)
+    return {key: dict(c, layer=layer) for key, c in caches.items()}
+
+
+def _drop_layer(caches):
+    if "layer" in caches:
+        return {key: c for key, c in caches.items() if key != "layer"}
+    return {key: _drop_layer(c) for key, c in caches.items()}
+
+
 def _scan_segment(layer_fn, stacked_params, h, caches, *, remat: bool):
-    """One segment's layers as a ``lax.scan``. Named scopes: ``layers`` holds
-    the scan, ``layer`` its body; operations in ``layers`` but in no
-    ``layer`` move each layer's slice of the stacked weights and caches in
-    and out."""
+    """One segment's layers as a ``lax.scan``.
+
+    In a decode step, a stacked attention KV cache on one device
+    (:func:`_carried`) travels in the scan's carry: each layer writes its
+    new row into the stack in place and reads its own K/V from it, so no
+    layer's slab is copied out and back and the caller's carry aliases the
+    scan's. Every other cache (and none, in training) is scanned as ``xs``
+    and comes back as ``ys``.
+
+    Named scopes: ``layers`` holds the scan, ``layer`` its body; operations
+    in ``layers`` but in no ``layer`` move each layer's slice of the stacked
+    weights (and of an ``xs`` cache) in and out."""
     body = layer_fn
     if remat:
         body = jax.checkpoint(layer_fn, prevent_cse=False)
+
+    if _carried(caches, h.shape[1]):
+        def carried_fn(carry, xs):
+            h, cache = carry
+            p, layer = xs
+            with jax.named_scope("layer"):
+                h, new_cache, aux = body(p, h, _layer_view(cache, layer))
+            return (h, _drop_layer(new_cache)), aux
+
+        n = jax.tree.leaves(stacked_params)[0].shape[0]
+        with jax.named_scope("layers"):
+            (h, new_caches), auxs = jax.lax.scan(
+                carried_fn, (h, caches),
+                (stacked_params, jnp.arange(n, dtype=jnp.int32)))
+        return h, new_caches, auxs
 
     def scan_fn(h, xs):
         p, cache = xs

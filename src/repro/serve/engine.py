@@ -223,7 +223,8 @@ def make_decode_burst(model: ModelApi, ctx: EngineContext, burst: int,
     active requests' temperatures.
 
     Named scopes in the compiled program: ``burst`` holds the scan (alone,
-    the loop's copies of its carried state and the embedding lookup), and
+    the embedding lookup and the loop's small state; the KV cache in its
+    carry is the layer scan's carry too, updated in place, not copied), and
     in it the model's (``layers``, ``layer``, ``attention.*``,
     ``dot.<backend>``, ``lm_head``) and ``sample``: each step's fault
     probe, token choice and margin.

@@ -165,3 +165,100 @@ def test_bucket_length_properties():
         b = bucket_length(plen, 64)
         assert b >= min(plen, 64) and b <= 64
         assert b & (b - 1) == 0 or b == 64  # pow2 unless clamped
+
+
+# ---------------------------------------------------------------------------
+# carried stacked cache against a per-layer loop
+# ---------------------------------------------------------------------------
+
+
+def _gqa_config():
+    """qwen3-8b reduced keeps 4 KV heads for 4 query heads: give it 2."""
+    import dataclasses
+
+    return dataclasses.replace(reduced(get_config("qwen3-8b")), num_kv_heads=2)
+
+
+@pytest.fixture(scope="module", params=["olmo-1b", "qwen3-gqa", "llama4-pair"])
+def family(request):
+    cfg = {
+        "olmo-1b": lambda: reduced(get_config("olmo-1b")),
+        "qwen3-gqa": _gqa_config,
+        "llama4-pair": lambda: reduced(get_config("llama4-maverick-400b-a17b")),
+    }[request.param]()
+    model = get_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(1))
+
+
+def _layer_loop_decode(params, tokens, cache, cfg):
+    """Reference decode: each segment's layers as a Python loop, each layer
+    handed its own slice ``cache[l]`` and writing it with
+    ``blocks.cache_row_write``; the slices are stacked back afterwards."""
+    from repro.models import transformer as T
+
+    h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
+    index = T._cache_index(cache)
+    positions = index[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    out = {}
+    for i, (kind, n) in enumerate(T._segments(cfg)):
+        key = f"seg{i}_{kind}"
+        new = []
+        for layer in range(n):
+            p = jax.tree.map(lambda a: a[layer], params[key])
+            c = jax.tree.map(lambda a: a[layer], cache[key])
+            if kind == "pair":
+                h, c_d, _ = T._dense_layer(p["dense"], h, cfg, EXACT, positions,
+                                           c["dense"])
+                h, c_m, _ = T._moe_layer(p["moe"], h, cfg, EXACT, positions,
+                                         c["moe"])
+                c = {"dense": c_d, "moe": c_m}
+            else:
+                layer_fn = T._moe_layer if kind == "moe" else T._dense_layer
+                h, c, _ = layer_fn(p, h, cfg, EXACT, positions, c)
+            new.append(c)
+        out[key] = jax.tree.map(lambda *a: jnp.stack(a), *new)
+    return T._lm_head(params, h, cfg, EXACT), out
+
+
+def _filled_cache(model, cfg, slots, max_len, index):
+    """A cache of random K/V rows with per-slot write indices ``index``."""
+    cache = model.make_cache(slots, max_len, dtype=jnp.float32)
+    leaves, treedef = jax.tree.flatten(cache)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    leaves = [leaf if jnp.issubdtype(leaf.dtype, jnp.integer)
+              else jax.random.normal(k, leaf.shape, leaf.dtype)
+              for k, leaf in zip(keys, leaves)]
+    cache = jax.tree.unflatten(treedef, leaves)
+    return with_cache_positions(cache, jnp.asarray(index, jnp.int32))
+
+
+@pytest.mark.parametrize(
+    "s, index",
+    [(1, [0, 5, 11]), (1, [15, 16, 20]), (4, [0, 3, 9]), (3, [13, 15, 16])],
+    ids=["one_token_mixed", "one_token_clamped_end", "block", "clamped_end"],
+)
+def test_carried_cache_matches_layer_loop(family, s, index):
+    """Decode gives the logits and the whole returned cache (k, v, index) of
+    a per-layer loop over ``cache_row_write`` on ``cache[l]``, bit for bit:
+    single-token steps, which carry the stacked cache through the layer
+    scan, at mixed per-slot indices and at the cache's end (index
+    ``max_len - 1`` and beyond, where the write clamps), and S > 1 blocks
+    (speculative verify, a prefill chunk), which scan it as xs, in the
+    middle and clamped at the end (index ``max_len - S`` and beyond)."""
+    cfg, model, params = family
+    max_len = 16
+    cache = _filled_cache(model, cfg, len(index), max_len, index)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (len(index), s), 0,
+                                cfg.vocab_size, jnp.int32)
+    got_logits, got = jax.jit(
+        lambda p, t, c: model.decode_step(p, t, c, EXACT))(params, tokens, cache)
+    want_logits, want = jax.jit(
+        lambda p, t, c: _layer_loop_decode(p, t, c, cfg))(params, tokens, cache)
+    np.testing.assert_array_equal(np.asarray(got_logits), np.asarray(want_logits))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the index advances by S even where the rows were clamped
+    np.testing.assert_array_equal(np.asarray(cache_positions(got)),
+                                  np.asarray(index) + s)

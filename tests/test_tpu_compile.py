@@ -140,3 +140,56 @@ def test_mla_decode_attention_compiles(one_chip):
         ((4, 1024, r), jnp.float32), ((4, 1024, rd), jnp.float32),
         ((4, 8), jnp.int32),
     )
+
+
+def test_decode_burst_carries_cache(one_chip):
+    """The greedy decode burst at olmo-1b widths (2 layers, 16 slots,
+    max_len 1026) keeps the stacked KV cache in the layer scan's carry: the
+    program materialises no ``copy`` or ``dynamic-slice`` of the shape of
+    one layer's or of the whole stack's K/V cache, and no fusion makes one
+    layer's slab; the attention fusions read the stack in place. Its
+    temporaries stay below one K+V cache (moving the cache as scan xs/ys
+    needs a whole stack of them)."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.core import EngineContext
+    from repro.models import get_model
+    from repro.serve.engine import _init_slot_state, make_decode_burst
+
+    layers, slots, max_len = 2, 16, 1026
+    cfg = dataclasses.replace(get_config("olmo-1b"), num_layers=layers)
+    model = get_model(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), tree)
+    params = place(model.abstract_params(jnp.bfloat16))
+    cache = place(model.make_cache(slots, max_len, dtype=jnp.float32,
+                                   abstract=True))
+    state = place(jax.eval_shape(lambda: _init_slot_state(slots)))
+    burst = jax.jit(make_decode_burst(model, EngineContext(mode="exact"), 8,
+                                      sampled=False), donate_argnums=(1, 2))
+    compiled = burst.lower(params, cache, state).compile()
+
+    # A fused computation reads its operands where they lie; what the
+    # program materialises is an instruction of any other computation.
+    text = compiled.as_text()
+    fused = set(re.findall(r"calls=(%[\w.-]+)", text))
+    layer = f"{slots},{max_len},{HEADS},{HEAD_DIM}"
+    slabs = {f"f32[{layer}]", f"f32[1,{layer}]"}
+    stack = f"f32[{layers},{layer}]"
+    movers, inside = [], None
+    for line in text.splitlines():
+        if line.endswith("{") and (head := re.match(r"(?:ENTRY )?(%\S+) ", line)):
+            inside = head.group(1)
+            continue
+        m = re.search(r"= (f32\[[0-9,]+\])\S* ([\w-]+)\(", line)
+        if inside in fused or not m:
+            continue
+        shape, op = m.groups()
+        if (op in ("copy", "dynamic-slice") and (shape in slabs or shape == stack)
+                or op == "fusion" and shape in slabs):
+            movers.append(line.strip()[:160])
+    assert not movers, movers
+    kv_bytes = 2 * layers * slots * max_len * HEADS * HEAD_DIM * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < kv_bytes
