@@ -9,6 +9,7 @@ the dry-run (ShapeDtypeStruct, no allocation).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -54,10 +55,17 @@ class SSMConfig:
 
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
-    """Zamba2-style hybrid: Mamba2 backbone + shared attention blocks."""
+    """Zamba2 hybrid (arXiv:2411.15242): every layer holds a Mamba2 mixer;
+    before its mixer, each layer in ``hybrid_layer_ids`` calls one of
+    ``num_mem_blocks`` shared transformer blocks (the ``k``-th such call
+    block ``k % num_mem_blocks``) on the concatenation of the residual
+    stream and the embedding output, through its own rank-``adapter_rank``
+    adapter on the MLP's gate/up projections and its own ``linear``. A
+    model of ``num_layers`` layers keeps the ids below it."""
 
-    attn_every: int = 9  # one shared-attn application per this many ssm layers
-    shared_attn_blocks: int = 1  # distinct shared blocks, used round-robin
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 2
+    adapter_rank: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +90,10 @@ class ModelConfig:
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 1e6
+    # softmax scale of attention scores; None: head_dim ** -0.5
+    attn_scale: Optional[float] = None
     norm_type: str = "rmsnorm"  # rmsnorm | layernorm | nonparametric
+    norm_eps: float = 1e-6  # rmsnorm epsilon (and the mixer's gated norm)
     act: str = "swish"  # MLP activation (multi-AF block mode)
     glu: bool = True  # gated MLP (SwiGLU-style)
     tie_embeddings: bool = False
@@ -149,11 +160,18 @@ def shape_applicable(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 
 
 def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 128) -> ModelConfig:
-    """Family-preserving small config for CPU smoke tests."""
+    """Family-preserving small config for CPU smoke tests. A hybrid keeps
+    the published layer pattern's shortest prefix in which every shared
+    block is called (``layers`` is ignored) and its attention head as wide
+    as the concatenated input over the heads."""
     scale = d_model / cfg.d_model
     heads = max(2, min(cfg.num_heads, 4))
     kv = max(1, min(cfg.num_kv_heads, heads))
     head_dim = max(16, d_model // heads)
+    if cfg.hybrid:
+        h = cfg.hybrid
+        layers = h.hybrid_layer_ids[h.num_mem_blocks - 1] + 1
+        head_dim = 2 * d_model // heads
     updates = dict(
         num_layers=layers,
         d_model=d_model,
@@ -180,12 +198,14 @@ def reduced(cfg: ModelConfig, *, layers: int = 2, d_model: int = 128) -> ModelCo
             q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16
         )
         updates["head_dim"] = 16
+    if cfg.attn_scale is not None:  # keep its ratio to head_dim ** -0.5
+        updates["attn_scale"] = cfg.attn_scale * math.sqrt(cfg.head_dim / head_dim)
     if cfg.ssm:
         updates["ssm"] = dataclasses.replace(
             cfg.ssm, state_dim=16, head_dim=16, chunk_size=32, num_heads=0
         )
     if cfg.hybrid:
-        updates["hybrid"] = dataclasses.replace(cfg.hybrid, attn_every=max(1, layers // 2))
+        updates["hybrid"] = dataclasses.replace(cfg.hybrid, adapter_rank=8)
     if cfg.encdec:
         updates["encdec"] = dataclasses.replace(cfg.encdec, encoder_layers=layers)
     return dataclasses.replace(cfg, **updates)

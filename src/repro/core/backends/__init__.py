@@ -68,7 +68,7 @@ for _b in (ExactBackend(), CarmenBackend(), Int8Backend(), KernelBackend()):
 # float: criticality-pinned or consumed outside the engine)
 _DOT_WEIGHT_NAMES = frozenset({
     "wq", "wk", "wv", "wo", "up", "gate", "down",
-    "in_proj", "out_proj", "wq_a", "wq_b", "wkv_a", "lm_head",
+    "in_proj", "out_proj", "wq_a", "wq_b", "wkv_a", "lm_head", "linear",
 })
 
 # param-tree key -> dot-time layer-name component (policy lookup only)
@@ -113,10 +113,8 @@ def _stacked_axes(keys, spec) -> int:
             else:
                 break
         return n
-    m = _SEG_RE.match(keys[0]) if keys else None
-    if m:
-        return 2 if m.group(1) == "hybrid" else 1
-    if keys and keys[0] in ("enc_layers", "dec_layers"):
+    if keys and (_SEG_RE.match(keys[0]) or keys[0] in ("enc_layers", "dec_layers",
+                                                       "shared")):
         return 1
     return 0
 

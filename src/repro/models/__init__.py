@@ -40,10 +40,13 @@ class ModelApi:
             return encdec.forward(prms, batch, self.cfg, ctx, remat=remat)
         return transformer.forward(prms, batch, self.cfg, ctx, remat=remat)
 
-    def decode_step(self, prms, tokens, cache, ctx: EngineContext):
+    def decode_step(self, prms, tokens, cache, ctx: EngineContext, clen=None):
+        """``clen``: a block's real rows, for recurrent state (see
+        ``transformer.decode_step``); attention-only caches need none."""
         if self.cfg.family == "audio":
             return encdec.decode_step(prms, tokens, cache, self.cfg, ctx)
-        return transformer.decode_step(prms, tokens, cache, self.cfg, ctx)
+        return transformer.decode_step(prms, tokens, cache, self.cfg, ctx,
+                                       clen=clen)
 
     def make_cache(self, batch: int, max_len: int, dtype=jnp.bfloat16, abstract: bool = False):
         if self.cfg.family == "audio":

@@ -49,7 +49,7 @@ def apply_norm(p, x, cfg: ModelConfig):
         return nonparametric_ln(x)
     if cfg.norm_type == "layernorm":
         return layernorm(x, p["scale"], p["bias"])
-    return rmsnorm(x, p["scale"])
+    return rmsnorm(x, p["scale"], cfg.norm_eps)
 
 
 def apply_af(x, mode: str, ctx: EngineContext):
@@ -80,12 +80,15 @@ def rope(x, positions, theta: float):
 # ---------------------------------------------------------------------------
 
 
-def attention_specs(cfg: ModelConfig):
+def attention_specs(cfg: ModelConfig, d_in: Optional[int] = None):
+    """Projections of one attention block; ``d_in`` is the width of its
+    input where that is not ``d_model`` (its output is ``d_model`` wide)."""
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    di = d_in or d
     specs = {
-        "wq": ParamSpec((d, h, hd), ("embed", "heads", "head_dim")),
-        "wk": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": ParamSpec((d, kv, hd), ("embed", "kv_heads", "head_dim")),
+        "wq": ParamSpec((di, h, hd), ("embed", "heads", "head_dim")),
+        "wk": ParamSpec((di, kv, hd), ("embed", "kv_heads", "head_dim")),
+        "wv": ParamSpec((di, kv, hd), ("embed", "kv_heads", "head_dim")),
         "wo": ParamSpec((h, hd, d), ("heads", "head_dim", "embed")),
     }
     if cfg.qkv_bias:
@@ -105,12 +108,20 @@ def _proj(ctx, x, w, b, name):
     return out.reshape(x.shape[:-1] + w.shape[1:])
 
 
-def _sdpa_chunked(q, k, v, q_positions, k_positions, causal: bool):
+def attn_scale(cfg: ModelConfig) -> float:
+    """Softmax scale of the scores: the config's, else ``head_dim ** -0.5``."""
+    if cfg.attn_scale is not None:
+        return cfg.attn_scale
+    return 1.0 / math.sqrt(cfg.head_dim)
+
+
+def _sdpa_chunked(q, k, v, q_positions, k_positions, causal: bool,
+                  scale: Optional[float] = None):
     """q: (B,Sq,H,hd); k,v: (B,Sk,H,hd) (KV pre-repeated to H so the head dim
     shards over the model axis for EVERY kv_heads count — the 5-D (KV,G)
     layout forced head replication whenever kv_heads %% TP != 0, §Perf A)."""
     b, sq, h, hd = q.shape
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     n_chunks = max(1, sq // Q_CHUNK) if sq % Q_CHUNK == 0 else 1
     qc = q.reshape(b, n_chunks, sq // n_chunks, h, hd)
     qp = q_positions.reshape(n_chunks, sq // n_chunks)
@@ -131,7 +142,8 @@ def _sdpa_chunked(q, k, v, q_positions, k_positions, causal: bool):
 
 
 def _sdpa_flash_xla(q, k, v, q_positions, k_positions, causal: bool,
-                    q_chunk: int = 512, k_chunk: int = 512):
+                    q_chunk: int = 512, k_chunk: int = 512,
+                    scale: Optional[float] = None):
     """KV-chunked online-softmax attention (pure-JAX flash twin).
 
     q, k, v: (B,S,H,hd) (KV pre-repeated to H — see _sdpa_chunked). Never
@@ -142,7 +154,7 @@ def _sdpa_flash_xla(q, k, v, q_positions, k_positions, causal: bool,
     b, sq, h, hd = q.shape
     sk = k.shape[1]
     hd_v = v.shape[-1]  # may differ from hd (MLA: scores over R+r, values R)
-    scale = 1.0 / math.sqrt(hd)
+    scale = scale or 1.0 / math.sqrt(hd)
     qc = q_chunk if sq % q_chunk == 0 else sq
     kc = k_chunk if sk % k_chunk == 0 else sk
     nq, nk = sq // qc, sk // kc
@@ -277,9 +289,11 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
         k_pos = positions
         with jax.named_scope("attention.core"):
             if ctx.attn_impl == "flash":
-                out = _sdpa_flash_xla(q, kr, vr, positions, k_pos, causal=causal)
+                out = _sdpa_flash_xla(q, kr, vr, positions, k_pos,
+                                      causal=causal, scale=attn_scale(cfg))
             else:
-                out = _sdpa_chunked(q, kr, vr, positions, k_pos, causal=causal)
+                out = _sdpa_chunked(q, kr, vr, positions, k_pos,
+                                    causal=causal, scale=attn_scale(cfg))
         new_cache = None
     else:
         if "layer" in cache:
@@ -298,7 +312,7 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
             cv = cache_row_write(cache["v"], v, idx)
             new_cache = {"k": ck, "v": cv, "index": idx + s}
         s_max = ck.shape[1]
-        scale = 1.0 / math.sqrt(hd)
+        scale = attn_scale(cfg)
         from repro.sharding.partition import current_mesh_axes
 
         with jax.named_scope("attention.core"):
@@ -328,15 +342,6 @@ def attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name, ca
     return ctx.linear(out, wo, name=f"{name}.o"), new_cache
 
 
-def init_attn_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    kvh, hd = cfg.num_kv_heads, cfg.head_dim
-    return {
-        "k": jnp.zeros((batch, max_len, kvh, hd), dtype),
-        "v": jnp.zeros((batch, max_len, kvh, hd), dtype),
-        "index": jnp.zeros((batch,), jnp.int32),
-    }
-
-
 def attn_cache_specs(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     kvh, hd = cfg.num_kv_heads, cfg.head_dim
     return {
@@ -362,13 +367,20 @@ def mlp_specs(cfg: ModelConfig, d_ff: Optional[int] = None):
     return specs
 
 
-def mlp(p, x, cfg: ModelConfig, ctx: EngineContext, *, name):
+def mlp(p, x, cfg: ModelConfig, ctx: EngineContext, *, name, delta=None):
+    """``delta``, a pair ``(gate, up)`` (a per-call adapter's output, gated
+    MLP only), adds to the gate and up projections before the activation."""
     # linear_af fuses the dot and the activation epilogue into one Pallas
     # pass on the kernel backend; every other backend unfuses to the same
     # linear -> multi-AF chain as before
     if cfg.glu:
         up = ctx.linear(x, p["up"], name=f"{name}.up")
-        h = ctx.linear_af(x, p["gate"], af=cfg.act, name=f"{name}.gate") * up
+        if delta is None:
+            h = ctx.linear_af(x, p["gate"], af=cfg.act,
+                              name=f"{name}.gate") * up
+        else:
+            gate = ctx.linear(x, p["gate"], name=f"{name}.gate") + delta[0]
+            h = ctx.activate(gate, cfg.act) * (up + delta[1])
     else:
         h = ctx.linear_af(x, p["up"], af=cfg.act, name=f"{name}.up")
     return ctx.linear(h, p["down"], name=f"{name}.down")

@@ -152,15 +152,6 @@ def mla_attention(p, x, cfg: ModelConfig, ctx: EngineContext, *, positions, name
     return ctx.linear(out.reshape(b, s, h * vdim), wo, name=f"{name}.o"), new_cache
 
 
-def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
-    m = cfg.mla
-    return {
-        "c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), dtype),
-        "k_rope": jnp.zeros((batch, max_len, m.qk_rope_head_dim), dtype),
-        "index": jnp.zeros((batch,), jnp.int32),
-    }
-
-
 def mla_cache_specs(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
     m = cfg.mla
     return {

@@ -13,6 +13,12 @@ Design constraints that shaped this file:
   (``_scan_segment``).
 * **CARMEN everywhere**: all projections go through ``EngineContext``; MLP
   activations go through the multi-AF block mapping.
+* **hybrid (Zamba2)**: the published layer list, as runs of plain mamba
+  layers (each run a stacked scan) between hybrid layers (each a stack of
+  one, called in order). The ``k``-th hybrid layer calls shared block
+  ``k % num_mem_blocks`` on ``[h ; e]`` (``e`` the embedding output), with
+  its own adapter and ``linear``; the shared blocks' K/V live in one stacked
+  cache, ``shared_kv``, row ``k`` for the ``k``-th hybrid layer.
 """
 from __future__ import annotations
 
@@ -62,6 +68,33 @@ def _mamba_layer_specs(cfg: ModelConfig):
     return {"norm": blocks.norm_spec(cfg), "mixer": mamba2.mamba2_specs(cfg)}
 
 
+def _hybrid_layer_specs(cfg: ModelConfig):
+    """A hybrid layer's own weights: its mixer, the ``linear`` that takes the
+    shared block's output into the mixer's input, and its call's adapter on
+    the shared MLP's gate/up (``down`` to the rank, then ``gate``/``up``)."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.hybrid.adapter_rank
+    return dict(
+        _mamba_layer_specs(cfg),
+        linear=ParamSpec((d, d), ("embed", "embed")),
+        adapter={
+            "down": ParamSpec((d, r), ("embed", None)),
+            "gate": ParamSpec((r, f), (None, "mlp")),
+            "up": ParamSpec((r, f), (None, "mlp")),
+        },
+    )
+
+
+def _shared_block_specs(cfg: ModelConfig):
+    """One shared transformer block: attention on the ``2 * d_model`` wide
+    ``[h ; e]``, then the MLP on the norm of its output."""
+    return {
+        "attn_norm": blocks.norm_spec(cfg, 2 * cfg.d_model),
+        "attn": blocks.attention_specs(cfg, d_in=2 * cfg.d_model),
+        "mlp_norm": blocks.norm_spec(cfg),
+        "mlp": blocks.mlp_specs(cfg),
+    }
+
+
 def _segments(cfg: ModelConfig):
     """(kind, layer_count) segments; layer params stack within a segment."""
     if cfg.family in ("dense", "vlm"):
@@ -81,10 +114,20 @@ def _segments(cfg: ModelConfig):
     if cfg.family == "ssm":
         return [("mamba", cfg.num_layers)]
     if cfg.family == "hybrid":
-        per = cfg.hybrid.attn_every
-        assert cfg.num_layers % per == 0, (cfg.num_layers, per)
-        return [("hybrid", cfg.num_layers // per)]  # groups of (per mamba + shared attn)
+        segs, run = [], 0
+        for i in range(cfg.num_layers):
+            if i not in cfg.hybrid.hybrid_layer_ids:
+                run += 1
+                continue
+            if run:
+                segs.append(("mamba", run))
+            segs.append(("hybrid", 1))
+            run = 0
+        if run:
+            segs.append(("mamba", run))
+        return segs
     raise ValueError(cfg.family)
+
 
 
 def decoder_specs(cfg: ModelConfig):
@@ -113,16 +156,10 @@ def decoder_specs(cfg: ModelConfig):
         elif kind == "mamba":
             specs[key] = stack_layers(lambda: _mamba_layer_specs(cfg), n)
         elif kind == "hybrid":
-            per = cfg.hybrid.attn_every
-            specs[key] = stack_layers(
-                lambda: stack_layers(lambda: _mamba_layer_specs(cfg), per), n
-            )
-            specs["shared_attn"] = {
-                "attn_norm": blocks.norm_spec(cfg),
-                "attn": blocks.attention_specs(cfg),
-                "mlp_norm": blocks.norm_spec(cfg),
-                "mlp": blocks.mlp_specs(cfg),
-            }
+            specs[key] = stack_layers(lambda: _hybrid_layer_specs(cfg), n)
+    if cfg.family == "hybrid":
+        specs["shared"] = stack_layers(lambda: _shared_block_specs(cfg),
+                                       cfg.hybrid.num_mem_blocks)
     return specs
 
 
@@ -161,11 +198,57 @@ def _moe_layer(p, h, cfg, ctx, positions, cache, name="layer"):
     return h + out, new_cache, aux
 
 
-def _mamba_layer(p, h, cfg, ctx, state, name="layer"):
+def _mamba_layer(p, h, cfg, ctx, state, name="layer", *, clen=None, extra=None):
+    """``h + Mamba2(norm(h + extra))``: ``extra``, a hybrid layer's shared
+    block output, enters the mixer's input and not the residual."""
     h = constrain(h, "batch", None, None)
-    x = blocks.apply_norm(p["norm"], h, cfg)
-    out, new_state = mamba2.mamba2_forward(p["mixer"], x, cfg, ctx, name=f"{name}.mixer", state=state)
+    x = blocks.apply_norm(p["norm"], h if extra is None else h + extra, cfg)
+    out, new_state = mamba2.mamba2_forward(p["mixer"], x, cfg, ctx, name=f"{name}.mixer",
+                                           state=state, clen=clen)
     return h + out, new_state
+
+
+def _kv_row(kv, k: int, block: int):
+    """Hybrid layer ``k``'s view of the stacked ``shared_kv`` cache: in a
+    decode step the whole stack, written in place (``_carried``); else its
+    own slab."""
+    if _carried(kv, block):
+        return dict(kv, layer=jnp.int32(k))
+    return jax.tree.map(lambda a: a[k], kv)
+
+
+def _kv_put(kv, k: int, new):
+    """The stack after hybrid layer ``k``'s attention wrote ``new``."""
+    if "layer" in new:
+        return _drop_layer(new)
+    return jax.tree.map(lambda a, n: a.at[k].set(n), kv, new)
+
+
+def _hybrid_layer(p, shared, h, e, cfg, ctx, positions, state, kv, k: int,
+                  clen=None):
+    """The ``k``-th hybrid layer (Zamba2): shared block ``k % num_mem_blocks``
+    on ``[h ; e]`` through this call's adapter, this layer's ``linear``, and
+    its mixer. Returns ``(h, new_state, new_kv)``; ``kv`` is the stacked
+    ``shared_kv`` cache (None without a cache).
+
+    Named scopes: ``shared`` holds the block, ``shared.adapter`` the
+    adapter; attention keeps its own (``attention.*``)."""
+    sp = jax.tree.map(lambda a: a[k % cfg.hybrid.num_mem_blocks], shared)
+    with jax.named_scope("shared"):
+        u = blocks.apply_norm(sp["attn_norm"], jnp.concatenate([h, e], -1), cfg)
+        cache = None if kv is None else _kv_row(kv, k, h.shape[1])
+        a, new_kv = blocks.attention(sp["attn"], u, cfg, ctx, positions=positions,
+                                     name="shared.attn", cache=cache)
+        x = blocks.apply_norm(sp["mlp_norm"], a, cfg)
+        ad = p["adapter"]
+        with jax.named_scope("shared.adapter"):
+            r = ctx.linear(x, ad["down"], name="layer.adapter.down")
+            delta = (ctx.linear(r, ad["gate"], name="layer.adapter.gate"),
+                     ctx.linear(r, ad["up"], name="layer.adapter.up"))
+        t = blocks.mlp(sp["mlp"], x, cfg, ctx, name="shared.mlp", delta=delta)
+        t = ctx.linear(t, p["linear"], name="layer.linear")
+    h, new_state = _mamba_layer(p, h, cfg, ctx, state, clen=clen, extra=t)
+    return h, new_state, None if kv is None else _kv_put(kv, k, new_kv)
 
 
 # ---------------------------------------------------------------------------
@@ -178,7 +261,7 @@ _ATTN_CACHE = frozenset(("k", "v", "index"))
 
 def _carried(caches, block: int) -> bool:
     """Whether a segment's cache travels in the layer scan's carry: a stacked
-    attention KV cache (``blocks.init_attn_cache``), or a dict of them
+    attention KV cache (``blocks.attn_cache_specs``), or a dict of them
     (``pair``), on one device, written one token a row (``block`` 1, the
     decode step). Recurrent state and MLA caches do not, nor does any cache
     under a mesh, where ``blocks.cache_row_write``'s gather-and-select form
@@ -256,10 +339,17 @@ def _scan_segment(layer_fn, stacked_params, h, caches, *, remat: bool):
     return h, new_caches, auxs
 
 
-def _run_segments(params, h, cfg, ctx, positions, caches, *, remat: bool):
-    """caches: dict seg_key -> stacked cache (or None). Returns h, caches, aux."""
+def _run_segments(params, h, cfg, ctx, positions, caches, *, remat: bool,
+                  clen=None):
+    """caches: dict seg_key -> stacked cache (or None). Returns h, caches, aux.
+
+    ``clen``: in a block of cached tokens, the rows that are real (scalar or
+    (B,)); recurrent mixers leave their state as it was past it."""
     new_caches = {}
     lb_loss = jnp.zeros((), jnp.float32)
+    e, kv, k = h, None, 0  # hybrid: the embedding output, shared K/V, calls
+    if cfg.family == "hybrid" and caches:
+        kv = caches["shared_kv"]
     for i, (kind, n) in enumerate(_segments(cfg)):
         key = f"seg{i}_{kind}"
         seg_cache = caches.get(key) if caches else None
@@ -286,32 +376,26 @@ def _run_segments(params, h, cfg, ctx, positions, caches, *, remat: bool):
         elif kind == "mamba":
 
             def mamba_fn(p, h, c):
-                h, ns = _mamba_layer(p, h, cfg, ctx, c)
+                h, ns = _mamba_layer(p, h, cfg, ctx, c, clen=clen)
                 return h, ns, {}
 
             h, nc, _ = _scan_segment(mamba_fn, params[key], h, seg_cache, remat=remat)
             new_caches[key] = nc
         elif kind == "hybrid":
-            shared = params["shared_attn"]
+            one = lambda t: None if t is None else jax.tree.map(lambda a: a[0], t)
 
-            def group_fn(p, h, c):
-                c_ssm = (c or {}).get("ssm"), (c or {}).get("attn")
+            def hybrid_fn(p, h, state, kv, k=k):
+                return _hybrid_layer(p, params["shared"], h, e, cfg, ctx,
+                                     positions, state, kv, k, clen)
 
-                def inner(h, xs):
-                    pl, cl = xs
-                    h, ns = _mamba_layer(pl, h, cfg, ctx, cl)
-                    return h, ns
-
-                h, new_ssm = jax.lax.scan(inner, h, (p, c_ssm[0]))
-                h, new_attn = _attn_block(
-                    shared, h, cfg, ctx, positions, c_ssm[1], "shared.attn"
-                )
-                x = blocks.apply_norm(shared["mlp_norm"], h, cfg)
-                h = h + blocks.mlp(shared["mlp"], x, cfg, ctx, name="shared.mlp")
-                return h, {"ssm": new_ssm, "attn": new_attn}, {}
-
-            h, nc, _ = _scan_segment(group_fn, params[key], h, seg_cache, remat=remat)
-            new_caches[key] = nc
+            if remat:
+                hybrid_fn = jax.checkpoint(hybrid_fn, prevent_cse=False)
+            with jax.named_scope("layer"):
+                h, ns, kv = hybrid_fn(one(params[key]), h, one(seg_cache), kv)
+            new_caches[key] = jax.tree.map(lambda a: a[None], ns)
+            k += 1
+    if kv is not None:
+        new_caches["shared_kv"] = kv
     return h, new_caches, {"lb_loss": lb_loss}
 
 
@@ -321,41 +405,42 @@ def _run_segments(params, h, cfg, ctx, positions, caches, *, remat: bool):
 
 
 def _seg_cache(cfg, kind, n, batch, max_len, dtype, abstract: bool):
+    """One segment's stacked cache: its shapes, or (``abstract`` False)
+    zeros of them, made at the stacked shape at once (no per-layer copy to
+    stack, which would hold the cache twice while it is made)."""
     def attn_c():
-        if cfg.mla:
-            f = mla.mla_cache_specs if abstract else mla.init_mla_cache
-        else:
-            f = blocks.attn_cache_specs if abstract else blocks.init_attn_cache
+        f = mla.mla_cache_specs if cfg.mla else blocks.attn_cache_specs
         return f(cfg, batch, max_len, dtype)
 
     def mamba_c():
-        f = mamba2.mamba_state_specs if abstract else mamba2.init_mamba_state
-        return f(cfg, batch, dtype)
+        return mamba2.mamba_state_specs(cfg, batch, dtype)
 
     def stack(tree, m):
+        shapes = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((m,) + s.shape, s.dtype), tree)
         if abstract:
-            return jax.tree.map(
-                lambda s: jax.ShapeDtypeStruct((m,) + s.shape, s.dtype), tree
-            )
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (m,) + a.shape).copy(), tree)
+            return shapes
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
-    if kind in ("dense", "dense_prefix", "moe"):
+    if kind in ("dense", "dense_prefix", "moe", "shared_kv"):
         return stack(attn_c(), n)
     if kind == "pair":
         return stack({"dense": attn_c(), "moe": attn_c()}, n)
-    if kind == "mamba":
+    if kind in ("mamba", "hybrid"):
         return stack(mamba_c(), n)
-    if kind == "hybrid":
-        per = cfg.hybrid.attn_every
-        return stack({"ssm": stack(mamba_c(), per), "attn": attn_c()}, n)
     raise ValueError(kind)
 
 
 def make_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16, abstract=False):
-    return {
+    caches = {
         f"seg{i}_{kind}": _seg_cache(cfg, kind, n, batch, max_len, dtype, abstract)
         for i, (kind, n) in enumerate(_segments(cfg))
     }
+    if cfg.family == "hybrid":
+        n = sum(kind == "hybrid" for kind, _ in _segments(cfg))
+        caches["shared_kv"] = _seg_cache(cfg, "shared_kv", n, batch, max_len,
+                                         dtype, abstract)
+    return caches
 
 
 # ---------------------------------------------------------------------------
@@ -397,18 +482,22 @@ def _lm_head(params, h, cfg, ctx):
     return ctx.linear(h, w, name="lm_head").astype(jnp.float32)
 
 
-def decode_step(params, tokens, cache, cfg: ModelConfig, ctx: EngineContext):
+def decode_step(params, tokens, cache, cfg: ModelConfig, ctx: EngineContext,
+                clen=None):
     """Cached decode: tokens (B, S) + cache -> (logits (B, S, V), cache).
 
     S = 1 is the classic one-token decode step; S > 1 writes a whole block
     (batched prefill: the serving engine feeds the full prompt in one call
-    and scatters the resulting KV into its slot cache).
+    and scatters the resulting KV into its slot cache). ``clen`` (scalar or
+    (B,)) counts a block's real rows: recurrent state ignores the rest
+    (attention caches hide them behind the rewound write index).
     """
     h = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
     h = constrain(h, "batch", None, None)
     index = _cache_index(cache)  # (B,) per-row decode positions
     positions = index[:, None] + jnp.arange(tokens.shape[1])[None, :]  # (B, S)
-    h, new_caches, _ = _run_segments(params, h, cfg, ctx, positions, cache, remat=False)
+    h, new_caches, _ = _run_segments(params, h, cfg, ctx, positions, cache,
+                                     remat=False, clen=clen)
     logits = _lm_head(params, h, cfg, ctx)
     return logits, new_caches
 

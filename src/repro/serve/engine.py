@@ -25,9 +25,11 @@ paths on top:
   behind the per-query-causal mask and reclaimed by decode) — prefill
   compiles O(log max_len) programs instead of one per distinct prompt
   length, and cache insertion is not an eager ``jax.tree.map`` anymore.
-  Recurrent-state families (ssm/hybrid/audio) prefill through a jitted
-  ``lax.scan`` over the padded prompt with masked state updates — same
-  bucketing, no per-token host round-trip;
+  Recurrent-state families (ssm/hybrid) run the same block program: their
+  mixers start from the carried state and leave it as it was past the
+  prompt's length (the chunked SSD form). The audio family prefills through
+  a jitted ``lax.scan`` over the padded prompt with masked state updates —
+  same bucketing, no per-token host round-trip;
 * **runtime-adaptive precision** (``repro.runtime``): pass a
   :class:`~repro.runtime.controller.ModeController` and each decode burst
   executes at the controller's current execution point — a different
@@ -94,8 +96,12 @@ from repro.sharding import partition
 from .kvcache import bucket_length, scatter_rows, with_cache_positions
 
 # families whose decode caches are pure attention/MLA KV rows (scatterable,
-# index-rewindable); recurrent-state families prefill via the masked scan
+# index-rewindable: speculative rollback and cache faults need that)
 _BATCHED_PREFILL_FAMILIES = ("dense", "vlm", "moe")
+# families that prefill a prompt (or chunk) as one block; the recurrent ones
+# mask their state past the block's length. The rest (audio) prefill through
+# the per-token masked scan
+_BLOCK_PREFILL_FAMILIES = _BATCHED_PREFILL_FAMILIES + ("ssm", "hybrid")
 
 
 def exact_rounding(ctx: EngineContext) -> Optional[Dict]:
@@ -269,7 +275,9 @@ def make_decode_burst(model: ModelApi, ctx: EngineContext, burst: int,
 
 
 def make_prefill_chunk(model: ModelApi, ctx: EngineContext):
-    """One chunked-prefill step for attention/MLA families.
+    """One chunked-prefill step for the block-prefill families (attention,
+    MLA, and recurrent state, whose mixers start from the row's carried
+    state and leave it as it was past ``clen``).
 
     ``(tree, row, last, tokens (1, Cb), start, clen) -> (row, last (1, V))``.
     ``row`` is the request's PRIVATE single-row cache with its write index at
@@ -290,7 +298,7 @@ def make_prefill_chunk(model: ModelApi, ctx: EngineContext):
     """
 
     def chunk(tree, row, last, tokens, start, clen):
-        logits, row = model.decode_step(tree, tokens, row, ctx)
+        logits, row = model.decode_step(tree, tokens, row, ctx, clen=clen)
         new_last = jax.lax.dynamic_slice_in_dim(logits, clen - 1, 1, axis=1)
         new_last = new_last[:, 0, :].astype(jnp.float32)
         row = with_cache_positions(row, (start + clen)[None])
@@ -300,8 +308,8 @@ def make_prefill_chunk(model: ModelApi, ctx: EngineContext):
 
 
 def make_scan_chunk(model: ModelApi, ctx: EngineContext):
-    """Chunked prefill for recurrent-state families: the masked-scan prefill
-    over one chunk, with the (state, last-logits) carry crossing chunks.
+    """Chunked prefill for the audio family: the masked-scan prefill over
+    one chunk, with the (state, last-logits) carry crossing chunks.
 
     Same signature as :func:`make_prefill_chunk`; ``start`` is unused (mixer
     state carries no positional index) and steps past ``clen`` run but have
@@ -342,7 +350,7 @@ def make_chunk_admit():
 
 
 def make_bucketed_prefill(model: ModelApi, ctx: EngineContext, max_len: int):
-    """Whole-prompt prefill for attention/MLA families, scatter included.
+    """Whole-prompt prefill for the block-prefill families, scatter included.
 
     ``(tree, cache, state, tokens (1, Pb), plen, slot, base_key, temp,
     max_new) -> (tok (1, 1), margin (1,), cache, state)``. ``tokens`` is the
@@ -350,14 +358,15 @@ def make_bucketed_prefill(model: ModelApi, ctx: EngineContext, max_len: int):
     dispatch ranks of real tokens are untouched); the first sampled token
     comes from the logits at ``plen - 1`` and the fresh row cache is written
     into slot ``slot`` with its index rewound to ``plen`` — the padded
-    tail's KV rows are invisible garbage, overwritten by decode.
+    tail's KV rows are invisible garbage, overwritten by decode, and
+    recurrent state ignores it.
 
     Compiles once per bucket shape: O(log max_len) programs total.
     """
 
     def prefill(tree, cache, state, tokens, plen, slot, base_key, temp, max_new):
         row = model.make_cache(1, max_len, dtype=jnp.float32)
-        logits, row = model.decode_step(tree, tokens, row, ctx)
+        logits, row = model.decode_step(tree, tokens, row, ctx, clen=plen)
         last = jax.lax.dynamic_slice_in_dim(logits, plen - 1, 1, axis=1)
         last = last[:, 0, :].astype(jnp.float32)
         row = with_cache_positions(row, plen[None])
@@ -368,8 +377,8 @@ def make_bucketed_prefill(model: ModelApi, ctx: EngineContext, max_len: int):
 
 
 def make_scan_prefill(model: ModelApi, ctx: EngineContext, max_len: int):
-    """Prefill for recurrent-state families (ssm/hybrid/audio): one jitted
-    ``lax.scan`` over the padded prompt instead of a per-token host loop.
+    """Prefill for the audio family: one jitted ``lax.scan`` over the padded
+    prompt instead of a per-token host loop.
 
     Steps past ``plen`` run but their state update is masked out
     (``jnp.where`` select on every cache leaf), so buckets compose with
@@ -524,6 +533,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
                     self.params, self.ctx.policy, self.ctx.mode, specs=self.model.specs()
                 )
         self.batched_prefill = self.model.cfg.family in _BATCHED_PREFILL_FAMILIES
+        self.block_prefill = self.model.cfg.family in _BLOCK_PREFILL_FAMILIES
         self.spec = None
         self.spec_telemetry = None
         if self.speculate is not None:
@@ -587,7 +597,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
         # Burst variants (sampled / all-greedy) compile lazily on first use.
         self._burst_fns = {}
         prefill_factory = (
-            make_bucketed_prefill if self.batched_prefill else make_scan_prefill
+            make_bucketed_prefill if self.block_prefill else make_scan_prefill
         )
         prefill_sharding_kwargs = {}
         if self.shardings is not None:
@@ -1129,7 +1139,7 @@ ServingShardings` bundle (``partition.serving_sharding_report`` summarizes
         (row + last-logits donated); ``admit`` is the shared
         :func:`_finish_prefill` tail (cache/state/row donated)."""
         if self._chunk_fns is None:
-            factory = (make_prefill_chunk if self.batched_prefill
+            factory = (make_prefill_chunk if self.block_prefill
                        else make_scan_chunk)
             if self.mesh is not None:
                 raise ValueError(

@@ -22,8 +22,9 @@ time. This module turns the same server into a streaming service:
   ``chunk_tokens`` rows per tick (:func:`~repro.serve.engine.
   make_prefill_chunk` — the per-query-causal mask plus the write-index
   rewind make a chunk attend exactly the rows the monolithic forward would
-  give it; recurrent families chunk their masked scan with the state as the
-  carry). Only the final chunk's admit program touches the shared slot cache
+  give it; recurrent mixers run the chunk from the row's carried state and
+  leave it as it was past the chunk's rows). Only the final chunk's admit
+  program touches the shared slot cache
   and transfers anything to the host, so a 10-chunk prefill still costs one
   host round-trip. Greedy token streams are identical to the monolithic
   path (asserted per family in ``tests/test_frontend.py``); inter-token

@@ -52,6 +52,25 @@ def test_family_features():
     assert get_config("internvl2-2b").frontend == "vision"
 
 
+def test_zamba2_is_the_published_model():
+    """configs/zamba2_7b.py against config.json of Zyphra/Zamba2-7B-Instruct
+    (the catalog row): the hybrid layer list, two shared blocks, adapters of
+    rank 128, attention of 32 x 224 on the 7168-wide concat scaled by
+    (224 / 2) ** -0.5, a GELU GLU of 14336, and the Mamba2 mixer."""
+    z = get_config("zamba2-7b")
+    assert z.hybrid.hybrid_layer_ids == (6, 11, 17, 23, 29, 35, 41, 47, 53,
+                                         59, 65, 71, 77)
+    assert z.hybrid.num_mem_blocks == 2 and z.hybrid.adapter_rank == 128
+    assert z.head_dim == 224 == 2 * z.d_model // z.num_heads
+    assert z.attn_scale == pytest.approx(112 ** -0.5)
+    assert (z.act, z.glu, z.d_ff, z.tie_embeddings) == ("gelu", True, 14336, True)
+    assert (z.norm_type, z.norm_eps, z.rope_theta) == ("rmsnorm", 1e-5, 1e4)
+    s = z.ssm
+    assert (s.num_heads, s.head_dim, s.n_groups, s.state_dim, s.conv_width,
+            s.chunk_size, s.expand) == (112, 64, 2, 64, 4, 256, 2)
+    assert s.num_heads * s.head_dim == 2 * z.d_model
+
+
 def test_long_500k_skip_rules():
     """long_500k runs iff sub-quadratic (per the assignment)."""
     runs = {a for a in ARCHS if shape_applicable(get_config(a), SHAPES["long_500k"])[0]}

@@ -3,8 +3,8 @@
 The frontend is a scheduling layer over the unchanged device-resident
 engine, so its core contract is the one every scheduling change in this
 repo carries: **greedy token streams are bit-identical to batch
-``run()``** — per model family (attention chunking and the recurrent scan
-carry are different programs), under sampling, under adaptive and
+``run()``** — per model family (attention chunking and the recurrent
+mixers' block from a carried state are different programs), under sampling, under adaptive and
 speculative serving, and regardless of when requests arrive relative to
 each other. On top of that ride the open-world behaviours ``run()`` cannot
 express: chunked prefill's interleaving bound (a long prompt admitted

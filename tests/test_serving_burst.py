@@ -75,10 +75,12 @@ def test_burst_greedy_bit_identical_to_per_token(arch):
 
 @pytest.mark.parametrize("arch", ["mamba2-780m", "zamba2-7b"])
 def test_recurrent_scan_prefill_burst_identical(arch):
-    """ssm / hybrid: the masked-scan prefill + burst decode match burst=1."""
+    """ssm / hybrid: the block prefill (mixers from the carried state,
+    masked past the prompt) + burst decode match burst=1."""
     cfg, model, params = _setup(arch)
     server = BatchedServer(model, EXACT, params, slots=2, max_len=32, burst=1)
-    assert not server.batched_prefill  # these take the scan-prefill path
+    # these prefill a block, but their state cannot be rewound
+    assert server.block_prefill and not server.batched_prefill
     ref = server.run(_requests(cfg, 3))
     out = BatchedServer(model, EXACT, params, slots=2, max_len=32,
                         burst=4).run(_requests(cfg, 3))
@@ -243,7 +245,7 @@ def test_bucketed_prefill_compile_count(olmo):
 
 
 def test_scan_prefill_compile_count():
-    """The recurrent-family scan prefill buckets too."""
+    """The recurrent-family block prefill buckets too."""
     cfg, model, params = _setup("mamba2-780m")
     server = BatchedServer(model, EXACT, params, slots=1, max_len=32, burst=4)
     rng = np.random.default_rng(3)

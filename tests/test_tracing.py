@@ -5,7 +5,8 @@ JAX profiler on the CPU: the host thread must hold every span of
 ``PROGRAM_SPANS``, nested as the module documents, with ``rid`` on the
 request spans, and the observer's serve trace must land on the profiler's
 clock. The compiled decode burst must carry every named scope in its
-``op_name`` metadata, and the compile counter must see a fresh chunk bucket
+``op_name`` metadata (a reduced Zamba2's burst and chunk programs the
+mixer's and the shared block's too), and the compile counter must see a fresh chunk bucket
 compile and a repeat of the same traffic compile nothing.
 """
 import glob
@@ -229,17 +230,50 @@ SCOPES = ("layers", "layer", "attention.kv_write", "attention.core",
           "lm_head", "sample")
 
 
-@pytest.mark.parametrize("mode,dot", [("int8", "dot.int8"),
-                                      ("kernel", "dot.kernel.xla_chain")])
-def test_compiled_burst_carries_every_scope(servers, mode, dot):
-    """On the CPU kernel mode runs the fused kernel's XLA chain."""
-    text = servers[mode].compiled_burst_text()
+def _scopes(text):
+    """Every scope named in a compiled program's ``op_name`` paths."""
     names = set()
     for line in text.splitlines():
         if 'op_name="' in line:
             names.update(line.split('op_name="', 1)[1].split('"', 1)[0]
                          .split("/"))
-    assert set(SCOPES) | {dot} <= names
+    return names
+
+
+@pytest.mark.parametrize("mode,dot", [("int8", "dot.int8"),
+                                      ("kernel", "dot.kernel.xla_chain")])
+def test_compiled_burst_carries_every_scope(servers, mode, dot):
+    """On the CPU kernel mode runs the fused kernel's XLA chain."""
+    text = servers[mode].compiled_burst_text()
+    assert set(SCOPES) | {dot} <= _scopes(text)
+
+
+# the hybrid's own scopes: the mixer (its conv, and the recurrent step in a
+# decode step or the SSD scan in a prefill block) and the shared block (its
+# adapter); the shared block's attention keeps attention.*
+ZAMBA_SCOPES = {
+    "burst": {"mamba", "mamba.conv", "mamba.step", "shared", "shared.adapter",
+              "attention.core", "attention.kv_write", "dot.int8", "layers",
+              "layer", "lm_head", "sample"},
+    "chunk": {"mamba", "mamba.conv", "mamba.scan", "shared", "shared.adapter",
+              "attention.core", "attention.kv_write", "dot.int8"},
+}
+
+
+@pytest.mark.parametrize("program", sorted(ZAMBA_SCOPES))
+def test_compiled_zamba2_programs_carry_the_hybrid_scopes(program):
+    cfg = reduced(get_config("zamba2-7b"))
+    model = get_model(cfg)
+    server = BatchedServer(model, _ctx("int8"), model.init(jax.random.PRNGKey(0)),
+                           slots=2, max_len=40, burst=4)
+    if program == "burst":
+        text = server.compiled_burst_text()
+    else:
+        row, last = server.fresh_row()
+        text = server.chunk_fns()[0].lower(
+            server.params, row, last, jnp.zeros((1, 8), jnp.int32),
+            jnp.int32(0), jnp.int32(5)).compile().as_text()
+    assert ZAMBA_SCOPES[program] <= _scopes(text)
 
 
 def test_compile_counter_attributes_a_fresh_bucket(olmo):

@@ -137,7 +137,8 @@ def test_sequential_prefill_isolated_from_active_slots():
     params = model.init(jax.random.PRNGKey(0))
     p = np.array([5, 17, 3], np.int32)
     server = BatchedServer(model, CTX, params, slots=2, max_len=32)
-    assert not server.batched_prefill  # ssm takes the sequential path
+    # ssm prefills as one block from the fresh row's state, not rewindably
+    assert server.block_prefill and not server.batched_prefill
     out = server.run([Request(0, p, 4), Request(1, p, 4)])
     assert out[0] == out[1]
     ref = BatchedServer(model, CTX, params, slots=1, max_len=32).run(
